@@ -1,9 +1,11 @@
 # Tier-1 verification and benchmarks — the commands CI runs, documented
 # here so they are reproducible locally.
 #
-#   make test        — the tier-1 suite (single CPU device in the main
-#                      process; distributed tests spawn subprocesses with 8
-#                      fake devices via tests/dist_helper.py)
+#   make test        — the tier-1 suite on the CPU (JAX_PLATFORMS=cpu,
+#                      Pallas kernels in interpret mode; single CPU device
+#                      in the main process; distributed tests spawn
+#                      subprocesses with 8 fake devices via
+#                      tests/dist_helper.py)
 #   make bench       — the benchmark driver (CSV to stdout)
 #   make bench-smoke — tiny-shapes pass of every suite + JSON artifact
 #                      (what the CI bench-smoke job runs)
@@ -14,12 +16,11 @@
 
 PY ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
-export JAX_PLATFORMS ?= cpu
 
 .PHONY: test bench bench-smoke bench-trend lint
 
 test:
-	$(PY) -m pytest -x -q
+	JAX_PLATFORMS=cpu $(PY) -m pytest -x -q
 
 bench:
 	$(PY) -m benchmarks.run

@@ -39,9 +39,12 @@ def time_us(fn, *args, warmup: int = 2, iters: int = 5) -> float:
 
 
 def run_with_devices(code: str, ndev: int = 8, timeout: int = 900) -> str:
-    """Run a snippet in a subprocess with N fake devices; returns stdout.
-    (The benchmark process itself keeps the default single device.)"""
+    """Run a snippet in a subprocess with N fake CPU devices; returns
+    stdout.  The child is a CPU correctness harness and is told so
+    (``JAX_PLATFORMS=cpu``): a parent that has touched JAX holds the
+    chip, and a child that reached for it would fail or hang."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", code], env=env,

@@ -111,6 +111,8 @@ def main() -> None:
                          args.fail_ratio))
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     # import after --smoke is in the environment so suites (and their
     # subprocess snippets) all observe the same mode

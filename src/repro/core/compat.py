@@ -1,70 +1,18 @@
-"""Version compatibility shims for the JAX APIs the core algorithms need.
+"""The JAX spellings the core algorithms share, for the one installed JAX.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` into the top-level
-``jax`` namespace around jax 0.5, and its replication-check kwarg was renamed
-``check_rep`` -> ``check_vma`` along the way; this repo must run on both (the
-pinned CI environment ships 0.4.x).  Everything in ``core/``, ``stream/``,
-and ``train/`` imports ``shard_map`` from here instead of reaching into
-``jax`` directly.
-
-This module also owns the pallas-TPU VMEM probe (``vmem_scratch``): the
-fused kernels allocate their accumulators via ``pltpu.VMEM``, whose import
-path is stable across the entire supported jax range (floor 0.4.30, pinned
-by the ``jax-floor`` CI job).  The probe runs at import time with an
-explicit version check — no blind try/except hiding a dead fallback — so
-the jax-floor job exercises it on every PR simply by importing ``repro.core``
-(the distributed shard it runs imports this module transitively).
+The repository supports exactly the JAX that ``requirements.txt`` pins
+(jax 0.9.0, with libtpu for the chip).  ``shard_map`` is
+``jax.shard_map`` — callers pass ``check_vma=False`` where a Pallas call
+sits in the body — and ``vmem_scratch`` is ``pltpu.VMEM``, the fused
+kernels' accumulator allocation.  Both are plain aliases: there is no
+version probe and no fallback.
 """
 from __future__ import annotations
 
-import inspect
-import re
-
 import jax
+from jax.experimental.pallas import tpu as _pltpu
 
-try:                                                   # jax >= 0.5
-    _shard_map = jax.shard_map
-except AttributeError:                                 # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
+shard_map = jax.shard_map
+vmem_scratch = _pltpu.VMEM
 
-_PARAMS = set(inspect.signature(_shard_map).parameters)
-
-# Leading digits only: pre-release suffixes ("0.8.0rc1", "...dev2025")
-# must not crash the import-time parse.
-JAX_VERSION = tuple(
-    int(re.match(r"\d+", x).group()) if re.match(r"\d+", x) else 0
-    for x in jax.__version__.split(".")[:3])
-
-# Import-time probe: on every supported jax (>= 0.4.30) the pallas TPU
-# namespace is importable on all backends, CPU-only hosts included — the
-# interpret-mode kernel tests depend on it.  Below the floor we record the
-# reason and fail loudly at *use* time instead of shipping a wrong API call.
-if JAX_VERSION >= (0, 4, 30):
-    from jax.experimental.pallas import tpu as _pltpu
-else:                                                  # pragma: no cover
-    _pltpu = None
-
-
-def vmem_scratch(shape, dtype):
-    """A pallas VMEM scratch allocation (the fused kernels' accumulator).
-
-    Single spelling (``pltpu.VMEM``) across the supported range; raises a
-    clear error rather than guessing an API below the jax floor.
-    """
-    if _pltpu is None:                                 # pragma: no cover
-        raise RuntimeError(
-            f"pallas VMEM scratch needs jax >= 0.4.30; have {jax.__version__}")
-    return _pltpu.VMEM(shape, dtype)
-
-
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` with the replication-check kwarg translated to
-    whatever name the installed jax understands."""
-    if "check_vma" in kwargs and "check_vma" not in _PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(f, **kwargs)
-
-
-__all__ = ["shard_map", "vmem_scratch", "JAX_VERSION"]
+__all__ = ["shard_map", "vmem_scratch"]

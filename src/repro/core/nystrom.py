@@ -54,7 +54,7 @@ from repro.obs import ledger as obs_ledger
 from repro.obs import trace as obs_trace
 
 from .compat import shard_map
-from .sketch import (DEFAULT_AXES, _PROG_CACHE_SIZE, SPARSE_KINDS,
+from .sketch import (DEFAULT_AXES, F32, _PROG_CACHE_SIZE, SPARSE_KINDS,
                      input_sharding, make_grid_mesh, omega_tile, rand_matmul,
                      seed_keys, validate_kind)
 
@@ -96,8 +96,8 @@ def nystrom_reference(A, seed: int, r: int, kind: str = "normal"):
     validate_kind(kind)
     n = A.shape[0]
     om = omega_tile(seed, 0, 0, n, r, kind, A.dtype)
-    B = A @ om
-    C = om.T @ B
+    B = jnp.matmul(A, om, precision=F32)
+    C = jnp.matmul(om.T, B, precision=F32)
     return B, C
 
 
@@ -155,7 +155,7 @@ def _sketch_rows_1d_prog(r: int, mesh: Mesh, axis: str, kind: str,
             return sketch_block(a_i, keys, r, kind=kind, backend=backend,
                                 blocks=blocks)    # (n/P, r) — no comm
 
-        kw = {} if backend == "jnp" else {"check_rep": False}
+        kw = {} if backend == "jnp" else {"check_vma": False}
         return shard_map(body, mesh=mesh,
                          in_specs=P(axis, None), out_specs=P(axis, None),
                          **kw)(A)
@@ -212,7 +212,7 @@ def _second_stage_no_redist_prog(r: int, mesh: Mesh, axis: str, kind: str,
             return jax.lax.psum_scatter(c_part, axis, scatter_dimension=0,
                                         tiled=True)   # (r/P, r2)
 
-        kw = {} if backend == "jnp" else {"check_rep": False}
+        kw = {} if backend == "jnp" else {"check_vma": False}
         return shard_map(body, mesh=mesh,
                          in_specs=P(axis, None), out_specs=P(axis, None),
                          **kw)(B)
@@ -256,7 +256,7 @@ def _second_stage_redist_prog(r: int, mesh: Mesh, axis: str, kind: str,
                                  backend=backend, blocks=blocks)
             return b_k, c_k                       # (r, r/P) — local
 
-        kw = {} if backend == "jnp" else {"check_rep": False}
+        kw = {} if backend == "jnp" else {"check_vma": False}
         return shard_map(body, mesh=mesh,
                          in_specs=P(axis, None),
                          out_specs=(P(None, axis), P(None, axis)), **kw)(B)
@@ -387,7 +387,7 @@ def _nystrom_general_prog(r: int, mesh: Mesh,
             return jax.lax.psum_scatter(c_part, a1, scatter_dimension=0,
                                         tiled=True)
 
-        kw = {} if backend == "jnp" else {"check_rep": False}
+        kw = {} if backend == "jnp" else {"check_vma": False}
         C = shard_map(stage2, mesh=mesh,
                       in_specs=P(a1, (a3, a2)),
                       out_specs=P((a2, a1), a3), **kw)(B)
@@ -484,7 +484,7 @@ def _two_grid_stage2_prog(r: int, mesh: Mesh, kind: str, salt: int,
             return jax.lax.psum_scatter(c_part, a1, scatter_dimension=0,
                                         tiled=True)
 
-        kw = {} if backend == "jnp" else {"check_rep": False}
+        kw = {} if backend == "jnp" else {"check_vma": False}
         return shard_map(body, mesh=mesh,
                          in_specs=P(a1, (a3, a2)),
                          out_specs=P((a2, a1), a3), **kw)(B)
@@ -615,7 +615,7 @@ def _nystrom_two_grid_fused_prog(r: int, shared, kind: str,
     p1, p2, p3 = shared.p
     in_spec = P(_spec_entry(pa1), _spec_entry(pa2 + pa3))
     b_p_spec = P(_spec_entry(pa1 + pa2), _spec_entry(pa3))
-    kw = {} if backend == "jnp" else {"check_rep": False}
+    kw = {} if backend == "jnp" else {"check_vma": False}
 
     def impl(A, keys):
         n = A.shape[0]
@@ -664,7 +664,7 @@ def _two_grid_stage2_fused_prog(r: int, n: int, shared, kind: str,
     mesh = shared.mesh
     pa1, pa2, pa3 = shared.p_axes
     b_p_spec = P(_spec_entry(pa1 + pa2), _spec_entry(pa3))
-    kw = {} if backend == "jnp" else {"check_rep": False}
+    kw = {} if backend == "jnp" else {"check_vma": False}
 
     def impl(B, keys):
         body, s2_in, s2_out = _two_grid_stage2_body(

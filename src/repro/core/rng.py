@@ -17,9 +17,9 @@ realizations of that insight:
    Philox-4x32-10 (the paper's exact generator, Salmon et al. SC'11),
    written only with uint32 ops and 16-bit-limb multiplies so the identical
    bitstream is reproducible inside a Pallas TPU kernel (no 64-bit multiply
-   on the TPU VPU).  ``kernels/sketch_matmul.py`` consumes these helpers to
-   generate Omega tiles in VMEM, and ``kernels/ref.py`` uses them as the
-   bitwise oracle.
+   on the TPU VPU).  ``kernels/local.py`` and ``kernels/sketch_matmul.py``
+   consume these helpers to generate Omega tiles in VMEM, and
+   ``kernels/ref.py`` uses them as the bitwise oracle.
 """
 from __future__ import annotations
 
@@ -101,8 +101,14 @@ def philox_4x32(counter: Tuple[jnp.ndarray, ...], key: Tuple[jnp.ndarray, jnp.nd
 
 
 def _uniform_from_u32(bits):
-    """uint32 -> float32 uniform in [0, 1) with 24-bit mantissa usage."""
-    return (bits >> 8).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    """uint32 -> float32 uniform in [0, 1) with 24-bit mantissa usage.
+
+    The convert goes through int32: after ``>> 8`` the value is below
+    2^24, so both converts are exact (same bits as a direct uint32 ->
+    float32), and Mosaic lowers int32 -> float32 but not uint32 -> float32.
+    """
+    return ((bits >> 8).astype(jnp.int32).astype(jnp.float32)
+            * jnp.float32(1.0 / (1 << 24)))
 
 
 # ---------------------------------------------------------------------------

@@ -33,6 +33,12 @@ from .grid import MatmulGrid, select_matmul_grid
 
 DEFAULT_AXES = ("p1", "p2", "p3")
 
+# The precision every sketch GEMM asks for: f32 products on f32 operands,
+# the contract the paths state.  On a TPU a default-precision f32 dot
+# runs single bf16 passes (about three decimal digits); HIGHEST asks for
+# the full f32 product.  XLA:CPU computes f32 dots in f32 either way.
+F32 = jax.lax.Precision.HIGHEST
+
 # The kind registry (DENSE_KINDS dense entry distributions applied by
 # GEMM; SPARSE_KINDS one-nonzero-per-row families applied in O(nnz) by
 # scatter-add) lives in the jax-free core/kinds.py so the plan layer can
@@ -194,7 +200,7 @@ def sketch_reference(A, seed, r: int, kind: str = "normal",
     om = omega_tile(seed, 0, 0, n2, r, kind, A.dtype)
     if scale is not None:
         om = om * jnp.asarray(scale, A.dtype)
-    return A @ om
+    return jnp.matmul(A, om, precision=F32)
 
 
 # ---------------------------------------------------------------------------
@@ -322,7 +328,7 @@ def _rand_matmul_prog(r: int, mesh: Mesh, axes: Tuple[str, str, str],
             return jax.lax.psum_scatter(b_partial, ax2, scatter_dimension=0,
                                         tiled=True)
 
-        kw = {} if backend == "jnp" else {"check_rep": False}
+        kw = {} if backend == "jnp" else {"check_vma": False}
         return shard_map(
             body, mesh=mesh,
             in_specs=P(ax1, (ax2, ax3)),
@@ -448,7 +454,7 @@ def _rand_matmul_communicating_prog(r: int, mesh: Mesh,
                                          tiled=True)
             om = jax.lax.dynamic_slice(
                 om_full, (j * blk_rows, k * blk_cols), (blk_rows, blk_cols))
-            b_partial = a_ij @ om
+            b_partial = jnp.matmul(a_ij, om, precision=F32)
             if p2 == 1:
                 return b_partial
             return jax.lax.psum_scatter(b_partial, ax2, scatter_dimension=0,

@@ -33,11 +33,14 @@ Backends:
 Bitwise contract (pinned by tests/test_local_backend.py): whenever the
 contraction dimension is not tiled (``nsteps_k == 1`` — guaranteed by the
 default block policy in interpret mode, which takes the whole operand as
-one tile), the Pallas backend reproduces the jnp backend bit for bit: the
-Irwin–Hall generator makes the Omega *entries* invariant to tiling and
-compilation context (core/rng.py), and an un-split ``lax.dot`` on the same
-f32 operands is the same reduction.  Tilings that split the contraction
-agree to f32 reduction order (~1e-6), same as any re-blocked GEMM.
+one tile), the interpreted ``sketch_block`` reproduces the jnp backend bit
+for bit: the Irwin–Hall generator makes the Omega *entries* invariant to
+tiling and compilation context (core/rng.py), and an un-split ``lax.dot``
+on the same f32 operands is the same reduction.  ``sketch_t_block``,
+native kernels and tilings that split the contraction agree to the f32
+summation-order bound (~1e-6 relative), same as any re-ordered GEMM: XLA
+orders a transposed-operand dot differently, and the MXU sums in its own
+order.  Every GEMM asks for f32 products (``core.sketch.F32``).
 
 HBM roofline (the point): per local GEMM the jnp backend touches
 ``m·k + k·n + m·n`` words (+ ``2·m·n`` more for a read-modify-write
@@ -53,7 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import rng
-from repro.core.sketch import omega_tile, seed_keys
+from repro.core.sketch import F32, omega_tile, seed_keys
 
 BACKENDS = ("jnp", "pallas", "auto")
 
@@ -84,17 +87,54 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-# VMEM budget for the default (no explicit ``blocks``) native-TPU tiling;
-# deliberately below the physical per-core VMEM so double buffering fits.
-_VMEM_BUDGET = 12 * 2 ** 20
+# Scoped VMEM the native kernels ask Mosaic for (v5e has 128 MiB per
+# core; Mosaic's default scope is 16 MiB), and the part of it the default
+# block policy and the autotuner's block filter may fill.
+VMEM_LIMIT = 40 * 2 ** 20
+VMEM_BUDGET = 32 * 2 ** 20
+
+# Omega entries generated per in-kernel step.  The Philox-4x32-10 /
+# Irwin-Hall generator keeps a dozen or so uint32 temporaries live per
+# entry; generated over a whole (bk, bn) tile at once they overflow VMEM
+# (a 4096x256 tile asked Mosaic for 58 MiB), so the kernels fill their
+# Omega tile in (gen_rows, bn) row slices inside a loop, and the slice's
+# working set is bounded no matter how large the tile is.
+_GEN_ENTRIES = 8192
+
+
+def gen_rows(bk: int, bn: int) -> int:
+    """Omega rows generated per in-kernel step for a (bk, bn) tile: the
+    largest multiple of 8 dividing ``bk`` with at most ``_GEN_ENTRIES``
+    entries (the whole tile when ``bk`` is not a multiple of 8, which only
+    an interpret-mode exact tile can be).
+    """
+    if bk % 8:
+        return bk
+    sk = min(bk, max(8, _GEN_ENTRIES // bn // 8 * 8))
+    while bk % sk:
+        sk -= 8
+    return sk
 
 
 def vmem_fit_bytes(bm: int, bn: int, bk: int, itemsize: int = 4) -> int:
-    """Resident VMEM bytes of one fused-GEMM tile set: the A (or B) panel,
-    the generated Omega tile, and the f32 accumulator + output tile.
-    Single source of truth for the default block policy here and the
-    autotuner's block-sweep filter (plan/autotune.py)."""
-    return itemsize * (bm * bk + bk * bn + 2 * bm * bn)
+    """Scoped VMEM bytes Mosaic allocates for one fused-GEMM kernel with
+    (bm, bn, bk) tiles — an upper bound fitted to the allocation the v5e
+    compiler asks for at eleven block shapes of ``sketch_block`` and
+    ``sketch_t_block`` (within 15% at the shapes the default policy picks).
+
+    Per entry of the A (or B) panel: its double buffer plus the f32 copy
+    and the three bf16 parts of the f32-precision dot.  Per entry of the
+    Omega tile: the scratch plus its copy and dot parts (the
+    ``gen_rows`` slice's generator working set is in these terms).  Per
+    output entry: the double-buffered accumulator input and output tiles,
+    the f32 accumulator and the dot's product.  ``sketch_t_block``'s
+    Omega tile is (bk, bm), so the Omega term takes the wider of bm and
+    bn.  Single source of truth for the default block policy here and the
+    autotuner's block-sweep filter (plan/autotune.py).
+    """
+    om = max(bm, bn)
+    return ((12 + 2 * itemsize) * bm * bk + 12 * bk * om
+            + 24 * bm * bn)
 
 
 def default_local_blocks(m: int, n: int, k: int,
@@ -104,23 +144,34 @@ def default_local_blocks(m: int, n: int, k: int,
     Interpret mode: one exact tile — no padding, no k split — so the
     kernel performs literally the same single ``lax.dot`` as the jnp
     body (the bitwise default the backend matrix tests pin).  Native TPU:
-    MXU-aligned tiles shrunk to the VMEM budget, splitting m then n and
-    only then the contraction (k splits cost the bitwise property).
+    MXU-aligned tiles shrunk to the VMEM budget.  The contraction is split
+    first (down to 512): every row block regenerates its Omega tiles, so
+    a tall ``bm`` is what keeps generation from scaling with ``m``; then
+    ``bm`` (down to 256), ``bn`` (down to 128), and ``bk`` to the floor.
     """
     if interpret:
         return (m, n, k)
     bm, bn, bk = _round_up(m, 8), _round_up(n, 128), _round_up(k, 128)
 
     def fit(bm, bn, bk):
-        return vmem_fit_bytes(bm, bn, bk) <= _VMEM_BUDGET
+        return vmem_fit_bytes(bm, bn, bk) <= VMEM_BUDGET
 
-    while not fit(bm, bn, bk) and bm > 256:
-        bm = _round_up(bm // 2, 8)
-    while not fit(bm, bn, bk) and bn > 256:
-        bn = _round_up(bn // 2, 128)
     while not fit(bm, bn, bk) and bk > 512:
         bk = _round_up(bk // 2, 128)
+    while not fit(bm, bn, bk) and bm > 256:
+        bm = _round_up(bm // 2, 8)
+    while not fit(bm, bn, bk) and bn > 128:
+        bn = _round_up(bn // 2, 128)
+    while not fit(bm, bn, bk) and bk > 128:
+        bk = _round_up(bk // 2, 128)
     return (bm, bn, bk)
+
+
+def _compiler_params(interpret: bool):
+    if interpret:
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT)
 
 
 # ---------------------------------------------------------------------------
@@ -137,6 +188,11 @@ def default_local_blocks(m: int, n: int, k: int,
 # bitwise for bf16 under exactly this rule.
 # ---------------------------------------------------------------------------
 
+def _prec(precision):
+    """The caller's precision, or the f32 contract (core/sketch.py F32)."""
+    return F32 if precision is None else precision
+
+
 def _omega_f32(seed, row0, col0, rows: int, cols: int, kind: str, salt: int,
                scale):
     om = omega_tile(seed, row0, col0, rows, cols, kind, jnp.float32,
@@ -149,7 +205,7 @@ def _omega_f32(seed, row0, col0, rows: int, cols: int, kind: str, salt: int,
 def _sketch_block_jnp(A, seed, cols, row0, col0, kind, salt, scale,
                       precision, acc, out_dtype):
     om = _omega_f32(seed, row0, col0, A.shape[1], cols, kind, salt, scale)
-    out = jnp.matmul(A.astype(jnp.float32), om, precision=precision)
+    out = jnp.matmul(A.astype(jnp.float32), om, precision=_prec(precision))
     if acc is not None:
         out = acc.astype(jnp.float32) + out
     return out.astype(out_dtype)
@@ -158,7 +214,8 @@ def _sketch_block_jnp(A, seed, cols, row0, col0, kind, salt, scale,
 def _sketch_t_block_jnp(B, seed, cols, row0, col0, kind, salt, scale,
                         precision, acc, out_dtype):
     om = _omega_f32(seed, row0, col0, B.shape[0], cols, kind, salt, scale)
-    out = jnp.matmul(om.T, B.astype(jnp.float32), precision=precision)
+    out = jnp.matmul(om.T, B.astype(jnp.float32),
+                     precision=_prec(precision))
     if acc is not None:
         out = acc.astype(jnp.float32) + out
     return out.astype(out_dtype)
@@ -191,39 +248,45 @@ def _om_block(meta_ref, r_off, c_off, rows: int, cols: int, kind: str,
     return om
 
 
-def _fwd_body(meta_ref, a_ref, o_ref, acc_ref, *, bk, bn, nsteps_k, kind,
-              salt, scale):
+def _fill_omega(meta_ref, om_ref, r_off, c_off, sk: int, kind: str,
+                salt: int, scale):
+    """Write the Omega tile at (r_off, c_off) into ``om_ref`` in (sk, cols)
+    row slices, so the generator's working set is one slice, not the
+    tile.  Entry bits depend only on global coordinates (core/rng.py), so
+    the slicing never changes them."""
     import jax.experimental.pallas as pl
-    k = pl.program_id(2)
-    j = pl.program_id(1)
+    rows, cols = om_ref.shape
 
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def step(s, carry):
+        r0 = pl.multiple_of(s * sk, 8)
+        om_ref[pl.ds(r0, sk), :] = _om_block(meta_ref, r_off + r0, c_off,
+                                             sk, cols, kind, salt, scale)
+        return carry
 
-    om = _om_block(meta_ref, k * bk, j * bn, bk, bn, kind, salt, scale)
-    a = a_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(a, om, preferred_element_type=jnp.float32)
-
-    @pl.when(k == nsteps_k - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+    jax.lax.fori_loop(0, rows // sk, step, 0)
 
 
-def _fwd_acc_body(meta_ref, a_ref, y_ref, o_ref, acc_ref, *, bk, bn,
-                  nsteps_k, kind, salt, scale):
+def _fwd_body(meta_ref, a_ref, *refs, bk, bn, sk, nsteps_k, kind, salt,
+              scale, acc):
+    """acc? + A·Omega on grid (i, j, k): Omega tile (k, j) in VMEM."""
     import jax.experimental.pallas as pl
+    if acc:
+        y_ref, o_ref, acc_ref, om_ref = refs
+    else:
+        o_ref, acc_ref, om_ref = refs
     k = pl.program_id(2)
     j = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         # the fused accumulation: Y enters the VMEM accumulator once...
-        acc_ref[...] = y_ref[...].astype(jnp.float32)
+        acc_ref[...] = (y_ref[...].astype(jnp.float32) if acc
+                        else jnp.zeros_like(acc_ref))
 
-    om = _om_block(meta_ref, k * bk, j * bn, bk, bn, kind, salt, scale)
+    _fill_omega(meta_ref, om_ref, k * bk, j * bn, sk, kind, salt, scale)
     a = a_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(a, om, preferred_element_type=jnp.float32)
+    acc_ref[...] += jax.lax.dot(a, om_ref[...], precision=F32,
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k == nsteps_k - 1)
     def _flush():
@@ -231,38 +294,26 @@ def _fwd_acc_body(meta_ref, a_ref, y_ref, o_ref, acc_ref, *, bk, bn,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _t_body(meta_ref, b_ref, o_ref, acc_ref, *, bk, bm, nsteps_k, kind,
-            salt, scale):
+def _t_body(meta_ref, b_ref, *refs, bk, bm, sk, nsteps_k, kind, salt, scale,
+            acc):
+    """acc? + Omega^T·B on grid (i, j, k): Omega tile (k, i) in VMEM."""
     import jax.experimental.pallas as pl
+    if acc:
+        w_ref, o_ref, acc_ref, om_ref = refs
+    else:
+        o_ref, acc_ref, om_ref = refs
     k = pl.program_id(2)
     i = pl.program_id(0)
 
     @pl.when(k == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        acc_ref[...] = (w_ref[...].astype(jnp.float32) if acc
+                        else jnp.zeros_like(acc_ref))
 
-    om = _om_block(meta_ref, k * bk, i * bm, bk, bm, kind, salt, scale)
+    _fill_omega(meta_ref, om_ref, k * bk, i * bm, sk, kind, salt, scale)
     b = b_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(om.T, b, preferred_element_type=jnp.float32)
-
-    @pl.when(k == nsteps_k - 1)
-    def _flush():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _t_acc_body(meta_ref, b_ref, w_ref, o_ref, acc_ref, *, bk, bm, nsteps_k,
-                kind, salt, scale):
-    import jax.experimental.pallas as pl
-    k = pl.program_id(2)
-    i = pl.program_id(0)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = w_ref[...].astype(jnp.float32)
-
-    om = _om_block(meta_ref, k * bk, i * bm, bk, bm, kind, salt, scale)
-    b = b_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(om.T, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += jax.lax.dot(om_ref[...].T, b, precision=F32,
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k == nsteps_k - 1)
     def _flush():
@@ -287,7 +338,6 @@ def _sketch_block_pallas(A, seed, cols, row0, col0, kind, salt, scale,
                          acc, out_dtype, blocks, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from repro.core.compat import vmem_scratch
 
     m, k = A.shape
     bm, bn, bk = blocks or default_local_blocks(m, cols, k, interpret)
@@ -301,9 +351,10 @@ def _sketch_block_pallas(A, seed, cols, row0, col0, kind, salt, scale,
     Ap = _pad2(A, mp, kp)
     meta = _meta(seed, row0, col0)
     grid = (mp // bm, np_ // bn, kp // bk)
-    body = _fwd_acc_body if acc is not None else _fwd_body
-    kernel = functools.partial(body, bk=bk, bn=bn, nsteps_k=kp // bk,
-                               kind=kind, salt=salt, scale=scale)
+    kernel = functools.partial(
+        _fwd_body, bk=bk, bn=bn, sk=gen_rows(bk, bn),
+        nsteps_k=kp // bk, kind=kind, salt=salt, scale=scale,
+        acc=acc is not None)
     in_specs = [pl.BlockSpec((bm, bk), lambda i, j, kk, m_: (i, kk))]
     operands = [meta, Ap]
     aliases = {}
@@ -314,11 +365,13 @@ def _sketch_block_pallas(A, seed, cols, row0, col0, kind, salt, scale,
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, m_: (i, j)),
-        scratch_shapes=[vmem_scratch((bm, bn), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                        pltpu.VMEM((bk, bn), jnp.float32)])
     out = pl.pallas_call(
         kernel, grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         input_output_aliases=aliases,
+        compiler_params=_compiler_params(interpret),
         interpret=interpret)(*operands)
     return out[:m, :cols]
 
@@ -327,7 +380,6 @@ def _sketch_t_block_pallas(B, seed, cols, row0, col0, kind, salt, scale,
                            acc, out_dtype, blocks, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    from repro.core.compat import vmem_scratch
 
     k, r2 = B.shape           # contraction over rows of B / rows of Omega
     bm, bn, bk = blocks or default_local_blocks(cols, r2, k, interpret)
@@ -337,9 +389,10 @@ def _sketch_t_block_pallas(B, seed, cols, row0, col0, kind, salt, scale,
     Bp = _pad2(B, kp, np_)
     meta = _meta(seed, row0, col0)
     grid = (mp // bm, np_ // bn, kp // bk)
-    body = _t_acc_body if acc is not None else _t_body
-    kernel = functools.partial(body, bk=bk, bm=bm, nsteps_k=kp // bk,
-                               kind=kind, salt=salt, scale=scale)
+    kernel = functools.partial(
+        _t_body, bk=bk, bm=bm, sk=gen_rows(bk, bm),
+        nsteps_k=kp // bk, kind=kind, salt=salt, scale=scale,
+        acc=acc is not None)
     in_specs = [pl.BlockSpec((bk, bn), lambda i, j, kk, m_: (kk, j))]
     operands = [meta, Bp]
     aliases = {}
@@ -350,11 +403,13 @@ def _sketch_t_block_pallas(B, seed, cols, row0, col0, kind, salt, scale,
     gs = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, m_: (i, j)),
-        scratch_shapes=[vmem_scratch((bm, bn), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
+                        pltpu.VMEM((bk, bm), jnp.float32)])
     out = pl.pallas_call(
         kernel, grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         input_output_aliases=aliases,
+        compiler_params=_compiler_params(interpret),
         interpret=interpret)(*operands)
     return out[:cols, :r2]
 
@@ -375,7 +430,7 @@ def _sketch_t_block_pallas(B, seed, cols, row0, col0, kind, salt, scale,
 
 def _gemm_jnp(A, B, alpha, precision, acc, out_dtype):
     out = jnp.matmul(A.astype(jnp.float32), B.astype(jnp.float32),
-                     precision=precision)
+                     precision=_prec(precision))
     if alpha != 1.0:
         out = out * jnp.float32(alpha)
     if acc is not None:
@@ -393,7 +448,8 @@ def _gemm_body(a_ref, b_ref, o_ref, acc_ref, *, nsteps_k, alpha):
 
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += jax.lax.dot(a, b, precision=F32,
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k == nsteps_k - 1)
     def _flush():
@@ -413,7 +469,8 @@ def _gemm_acc_body(a_ref, b_ref, y_ref, o_ref, acc_ref, *, nsteps_k, alpha):
 
     a = a_ref[...].astype(jnp.float32)
     b = b_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(a, b, preferred_element_type=jnp.float32)
+    acc_ref[...] += jax.lax.dot(a, b, precision=F32,
+                                preferred_element_type=jnp.float32)
 
     @pl.when(k == nsteps_k - 1)
     def _flush():
@@ -428,7 +485,7 @@ def _gemm_acc_body(a_ref, b_ref, y_ref, o_ref, acc_ref, *, nsteps_k, alpha):
 
 def _gemm_pallas(A, B, alpha, acc, out_dtype, blocks, interpret):
     import jax.experimental.pallas as pl
-    from repro.core.compat import vmem_scratch
+    from jax.experimental.pallas import tpu as pltpu
 
     m, k = A.shape
     _, n = B.shape
@@ -452,21 +509,28 @@ def _gemm_pallas(A, B, alpha, acc, out_dtype, blocks, interpret):
         kernel, grid=grid, in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
-        scratch_shapes=[vmem_scratch((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         input_output_aliases=aliases,
+        compiler_params=_compiler_params(interpret),
         interpret=interpret)(*operands)
     return out[:m, :n]
 
 
 # ---------------------------------------------------------------------------
 # Row-slab fold: Y += zero-padded dY placed at a traced row offset — the
-# streaming ``update_rows`` accumulation (stream/distributed.py).  The jnp
-# body materializes the zero-padded frame in HBM (write + read of
-# (k + 2m)·n words) before the slice-add; the pallas body performs the
-# identical concatenate + dynamic_slice + add INSIDE the kernel, so the
-# padded frame lives only in VMEM and Y (aliased in-place) makes one HBM
-# round trip.  Bitwise-identical by construction: both backends run the
-# same ops on the same operands.
+# streaming ``update_rows`` accumulation (stream/distributed.py) and the
+# ragged lanes of stream/state.py.  The jnp body materializes the
+# zero-padded frame [0_m; d; 0_m] in HBM ((k + 2m)·c words) before the
+# slice-add.  The pallas body grids Y in ``bm``-row blocks (Y aliased
+# in-place, one HBM round trip) and fetches each block's window of the
+# slab from a frame padded by only ``bm`` rows a side: the window start is
+# clipped into [0, k + bm], and a clipped window lies wholly in the zero
+# pad, exactly as the unclipped one lies wholly outside d.  Mosaic slices
+# rows only at multiples of 8 when the offset is traced, so the kernel
+# DMAs the 8-aligned (bm + 8)-row window that holds it and selects the
+# shift (0..7) among eight static slices.  Bitwise-identical to the jnp
+# body: both add the same slab rows (or +0.0) to the same Y rows, and the
+# masked form selects with the same ``where``.
 # ---------------------------------------------------------------------------
 
 def _fold_rows_jnp(y, d, start, nvalid=None):
@@ -485,56 +549,64 @@ def _fold_rows_jnp(y, d, start, nvalid=None):
     return jnp.where(live[:, None], y + win, y)
 
 
-def _fold_rows_body(meta_ref, y_ref, d_ref, o_ref, *, m, masked):
+def _fold_rows_body(meta_ref, y_ref, d_hbm, o_ref, buf, sem, *, m, k, bm,
+                    masked):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
     start = meta_ref[0]
+    g0 = pl.program_id(0) * bm            # first Y row of this block
+    src = jnp.clip(start + g0 - m + bm, 0, k + bm)
+    base = pl.multiple_of(src // 8 * 8, 8)
+    cp = pltpu.make_async_copy(d_hbm.at[pl.ds(base, bm + 8)], buf, sem)
+    cp.start()
+    cp.wait()
     y = y_ref[...]
-    d = d_ref[...]
-    pad = jnp.zeros((m, d.shape[1]), d.dtype)
-    dpad = jnp.concatenate([pad, d, pad], axis=0)
-    win = jax.lax.dynamic_slice(dpad, (start, 0), (m, d.shape[1]))
+    c = y.shape[1]
+    win = buf[0:bm, :c]
+    for s in range(1, 8):
+        win = jnp.where(src - base == s, buf[s:s + bm, :c], win)
+    win = win.astype(y.dtype)
     if masked:
-        idx = start + jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+        idx = start + g0 + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
         live = (idx >= m) & (idx < m + meta_ref[1])
-        o_ref[...] = jnp.where(live, y + win, y).astype(o_ref.dtype)
+        o_ref[...] = jnp.where(live, y + win, y)
     else:
-        o_ref[...] = (y + win).astype(o_ref.dtype)
+        o_ref[...] = y + win
 
 
-def _fold_rows_pallas(y, d, start, interpret, pad_to=None, nvalid=None):
+def _fold_rows_pallas(y, d, start, interpret, nvalid=None, block_rows=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     m, c = y.shape
     k = d.shape[0]
-    if pad_to is not None:            # tests: force the padded (native) path
-        mp, cp, kp = pad_to
-    elif interpret:
-        mp, cp, kp = m, c, k          # one exact tile — the bitwise default
-    else:
-        mp, cp, kp = _round_up(m, 8), _round_up(c, 128), _round_up(k, 8)
-    yp = _pad2(y, mp, cp)
-    dp = _pad2(d, kp, cp)
-    # The caller's ``start`` indexes a frame whose top pad is the LOGICAL
-    # shard height m; the in-kernel frame's top pad is the padded height
-    # mp, so shift by the difference — otherwise row-padding would slide
-    # the slab delta mp - m rows down (same padding contract as the
-    # sketch kernels: padding never shifts in-range placement).
+    # interpret mode: one block, the frame of the jnp body; natively
+    # 512-row blocks.  ``block_rows`` forces a tiling (tests).
+    bm = block_rows or (m if interpret else min(_round_up(m, 8), 512))
+    # the slab frame: bm zero rows above, bm + 8 below (the aligned
+    # window's overhang), rows to a multiple of 8, lanes to 128, and
+    # sub-f32 slabs held in f32 (bf16 -> f32 -> bf16 is exact)
+    fdt = jnp.float32 if jnp.dtype(d.dtype).itemsize < 4 else d.dtype
+    dp = jnp.pad(d.astype(fdt), ((bm, bm + 8 + _round_up(k, 8) - k),
+                                 (0, _round_up(c, 128) - c)))
     masked = nvalid is not None
-    meta = jnp.stack([
-        jnp.asarray(start, jnp.int32) + jnp.int32(mp - m),
-        jnp.asarray(nvalid if masked else k, jnp.int32)])
-    kernel = functools.partial(_fold_rows_body, m=mp, masked=masked)
+    meta = jnp.stack([jnp.asarray(start, jnp.int32),
+                      jnp.asarray(nvalid if masked else k, jnp.int32)])
+    kernel = functools.partial(_fold_rows_body, m=m, k=k, bm=bm,
+                               masked=masked)
     gs = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(1,),
-        in_specs=[pl.BlockSpec((mp, cp), lambda i, m_: (0, 0)),
-                  pl.BlockSpec((kp, cp), lambda i, m_: (0, 0))],
-        out_specs=pl.BlockSpec((mp, cp), lambda i, m_: (0, 0)))
-    out = pl.pallas_call(
+        num_scalar_prefetch=1, grid=(pl.cdiv(m, bm),),
+        in_specs=[pl.BlockSpec((bm, c), lambda i, m_: (i, 0)),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((bm, c), lambda i, m_: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((bm + 8, dp.shape[1]), fdt),
+                        pltpu.SemaphoreType.DMA(())])
+    return pl.pallas_call(
         kernel, grid_spec=gs,
-        out_shape=jax.ShapeDtypeStruct((mp, cp), y.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, c), y.dtype),
         input_output_aliases={1: 0},    # y aliases the output in-place
-        interpret=interpret)(meta, yp, dp)
-    return out[:m, :c]
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret)(meta, y, dp)
 
 
 def fold_rows_block(y, d, start, backend: str = "jnp", interpret=None,
@@ -545,10 +617,10 @@ def fold_rows_block(y, d, start, backend: str = "jnp", interpret=None,
     be traced (the shard-relative clipped offset, see
     ``stream/distributed.py``).  Shards outside the slab slice pure zeros,
     so row-disjoint ingest reproduces the full-shape path bitwise.  The
-    pallas backend keeps the zero-padded frame in VMEM and aliases ``y``
-    in-place — 2·m·c accumulate HBM words instead of the jnp body's
-    materialized-frame 4·k·c-class traffic (``plan.model``'s
-    ``stream_update_cost`` prices both).
+    pallas backend never builds the (k + 2m)-row frame: it DMAs each Y
+    block's slab window and aliases ``y`` in-place — 2·m·c accumulate HBM
+    words instead of the jnp body's materialized-frame traffic
+    (``plan.model``'s ``stream_update_cost`` prices both).
 
     ``nvalid`` (may be traced) restricts the fold to the first ``nvalid``
     rows of ``d``: y rows fed by rows >= nvalid keep their EXACT input
@@ -557,8 +629,8 @@ def fold_rows_block(y, d, start, backend: str = "jnp", interpret=None,
     add would flip a resident -0.0).  Both backends run the same
     mask + where on the same operands, so the fold stays bitwise-identical
     across backends, and this entry point vmaps over a leading lane axis
-    (the batched ragged programs vmap it directly — in interpret mode the
-    lane axis becomes one more grid dimension of the same kernel).
+    (the batched ragged programs vmap it directly — the lane axis becomes
+    one more grid dimension of the same kernel).
     """
     b = resolve_backend(backend)
     if b == "jnp":

@@ -25,9 +25,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import rng
-from repro.core.compat import vmem_scratch as _vmem_scratch
+from repro.core.sketch import F32
 
 
 # ---------------------------------------------------------------------------
@@ -65,7 +66,7 @@ def _sketch_matmul_body(a_ref, o_ref, acc_ref, *, seed: int, bk: int, bn: int,
 
     om = _omega_tile_kernel(seed, k * bk, j * bn, bk, bn, kind, salt)
     a = a_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(a, om,
+    acc_ref[...] += jax.lax.dot(a, om, precision=F32,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(k == nsteps_k - 1)
@@ -93,7 +94,7 @@ def sketch_matmul_pallas(A, seed: int, r: int, *,
         in_specs=[pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n1, r), out_dtype),
-        scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(A)
 
@@ -114,7 +115,7 @@ def _sketch_t_matmul_body(b_ref, o_ref, acc_ref, *, seed: int, bk: int,
     # Omega tile rows k*bk..k*bk+bk map to the contraction; cols i*bm..
     om = _omega_tile_kernel(seed, k * bk, i * bm, bk, bm, kind, salt)
     b = b_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot(om.T, b,
+    acc_ref[...] += jax.lax.dot(om.T, b, precision=F32,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(k == nsteps_k - 1)
@@ -141,7 +142,7 @@ def sketch_t_matmul_pallas(B, seed: int, r: int, *,
         in_specs=[pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, r2), out_dtype),
-        scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(B)
 

@@ -60,6 +60,8 @@ def main():
                     help="local GEMM bodies of the compressed exchange "
                          "(kernels/local.py; auto = pallas on TPU)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if not args.full:

@@ -102,6 +102,12 @@ class LedgerSite:
 
     # -- measured bytes (lazy, parsed once) ---------------------------------
 
+    def compiled_text(self) -> Optional[str]:
+        """The site's compiled executable as HLO text (None for
+        analytic-only sites): re-lowered at the dispatched signature, so
+        the compile is the one the dispatch cached."""
+        return None if self._hlo_thunk is None else self._hlo_thunk()
+
     def collectives(self):
         """The executable's parsed :class:`CollectiveBytes` (None for
         analytic-only sites); lowers + parses on first call, then cached."""
@@ -110,7 +116,7 @@ class LedgerSite:
                 self._cb = False
             else:
                 from repro.roofline.hlo import collective_bytes_of
-                self._cb = collective_bytes_of(self._hlo_thunk())
+                self._cb = collective_bytes_of(self.compiled_text())
         return None if self._cb is False else self._cb
 
     @property

@@ -167,9 +167,11 @@ def _synthetic_input(plan: Plan):
 # ---------------------------------------------------------------------------
 
 def _vmem_fits(blocks: dict, machine: M.MachineModel) -> bool:
-    from repro.kernels.local import vmem_fit_bytes
-    return vmem_fit_bytes(blocks["bm"], blocks["bn"],
-                          blocks["bk"]) <= machine.vmem_bytes
+    """Whether the kernels' scoped VMEM for ``blocks`` fits what they ask
+    Mosaic for — the scoped budget, not the core's physical VMEM."""
+    from repro.kernels.local import VMEM_BUDGET, vmem_fit_bytes
+    return vmem_fit_bytes(blocks["bm"], blocks["bn"], blocks["bk"]) <= min(
+        VMEM_BUDGET, machine.vmem_bytes)
 
 
 def _measurable_candidates(plan: Plan, machine: M.MachineModel,

@@ -112,29 +112,33 @@ PRESETS = {
 }
 
 
-def probe_machine(device=None) -> MachineModel:
-    """Best-effort preset from ``jax.devices()[0]`` (overridable everywhere).
+# ``device_kind`` as JAX reports it (lower-cased) -> preset name.  A chip
+# that is not listed has no preset: pricing it with another chip's peaks
+# would rank candidates on numbers nobody measured.
+_TPU_KINDS = {"tpu v5 lite": "tpu_v5e", "tpu v5e": "tpu_v5e",
+              "tpu v4": "tpu_v4"}
 
-    Never raises: unknown accelerators fall back to the v5e preset, unknown
-    hosts to the cpu preset, and an uninitialized backend to cpu.
+
+def probe_machine(device=None) -> MachineModel:
+    """The preset of ``device`` (default ``jax.devices()[0]``).
+
+    CPU devices get the cpu preset and TPUs whose ``device_kind`` is in
+    ``_TPU_KINDS`` their chip's preset.  Anything else raises ValueError
+    (pass ``machine=`` explicitly), and so does a backend that fails to
+    initialize — neither is mistaken for a host CPU.
     """
     if device is None:
-        try:
-            import jax
-            device = jax.devices()[0]
-        except Exception:
-            return PRESETS["cpu"]
-    platform = getattr(device, "platform", "cpu")
-    kind = (getattr(device, "device_kind", "") or "").lower()
-    if platform == "tpu":
-        if "v4" in kind:
-            return PRESETS["tpu_v4"]
-        return PRESETS["tpu_v5e"]
-    if platform == "cpu":
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
         return PRESETS["cpu"]
-    # gpu / unknown accelerator: v5e-class roofline is the closest preset
-    return dataclasses.replace(PRESETS["tpu_v5e"], name=platform,
-                               supports_pallas=False)
+    name = (_TPU_KINDS.get(str(device.device_kind).lower())
+            if device.platform == "tpu" else None)
+    if name is None:
+        raise ValueError(
+            f"no machine preset for {device.platform} device kind "
+            f"{device.device_kind!r}; pass machine= explicitly")
+    return PRESETS[name]
 
 
 def device_kind_tag(device=None) -> str:
@@ -380,8 +384,8 @@ def stream_update_cost(k: int, n2: int, r: int, l: int,
     and one W round trip (2·l·n2/(p2·p3)).  The traced-offset Y fold is
     backend-dispatched too (``kernels.local.fold_rows_block``): the jnp
     body round-trips dY plus the zero-padded frame (4·k·r/p3 accumulate
-    words), the pallas body keeps the padded frame in VMEM and aliases
-    the Y shard in-place (2·k·r/p3).
+    words), the pallas body DMAs each Y block's slab window (no padded
+    frame) and aliases the Y shard in-place (2·k·r/p3).
     """
     p1, p2, p3 = grid
     words = 0.0

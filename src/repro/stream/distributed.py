@@ -33,6 +33,7 @@ from repro.core.nystrom import (
 )
 from repro.core.sketch import (
     DEFAULT_AXES,
+    F32,
     input_sharding,
     omega_tile,
     output_sharding,
@@ -145,7 +146,8 @@ def corange_update(W, H, cfg: StreamConfig, mesh: Mesh,
         i = jax.lax.axis_index(ax1)
         if backend == "jnp":
             psi_c = psi_cols(cfg, i * br, br, seed=seed)   # (br, l)
-            part = psi_c.T.astype(h_blk.dtype) @ h_blk
+            part = jnp.matmul(psi_c.T.astype(h_blk.dtype), h_blk,
+                              precision=F32)
         else:
             part = sketch_t_block(
                 h_blk, cfg.seed if seed is None else seed, cfg.sketch_l,
@@ -153,7 +155,7 @@ def corange_update(W, H, cfg: StreamConfig, mesh: Mesh,
                 backend=backend, blocks=blocks)
         return w_blk + jax.lax.psum(part, ax1)
 
-    kw = {} if backend == "jnp" else {"check_rep": False}
+    kw = {} if backend == "jnp" else {"check_vma": False}
     fn = shard_map(body, mesh=mesh,
                    in_specs=(P(None, (ax2, ax3)), P(ax1, (ax2, ax3))),
                    out_specs=P(None, (ax2, ax3)), **kw)
@@ -228,7 +230,7 @@ def _sharded_update_prog(cfg: StreamConfig, mesh: Mesh,
 
     fused = shard_map(body, mesh=mesh,
                       in_specs=(P((ax1, ax2), ax3), P(ax1, (ax2, ax3))),
-                      out_specs=P((ax1, ax2), ax3), check_rep=False)
+                      out_specs=P((ax1, ax2), ax3), check_vma=False)
 
     def upd(Y, W, H):
         Y = fused(Y, H)
@@ -262,10 +264,10 @@ def _sharded_rowblock_prog(cfg: StreamConfig, mesh: Mesh,
     ``backend``: local GEMM body for the slab sketch and the Psi-slab
     product (kernels/local.py) — pallas keeps the Omega/Psi blocks out of
     HBM, and the traced-offset Y fold itself is fused too
-    (``fold_rows_block``: the zero-padded dY frame lives only in VMEM and
+    (``fold_rows_block``: the zero-padded dY frame is never built and
     the Y shard is aliased in-place, one HBM round trip instead of the
-    jnp body's materialized-frame traffic).  Both backends run the same
-    ops on the same operands, so the fold is bitwise-identical.
+    jnp body's materialized-frame traffic).  Both backends add the same
+    slab rows to the same Y rows, so the fold is bitwise-identical.
     """
     from repro.kernels.local import (fold_rows_block, sketch_block,
                                      sketch_t_block)
@@ -287,7 +289,8 @@ def _sharded_rowblock_prog(cfg: StreamConfig, mesh: Mesh,
             om = omega_tile(cfg.seed, j * om_rows, kk * r_cols,
                             om_rows, r_cols, cfg.kind, h_cols.dtype,
                             salt=cfg.omega_salt)
-            part = h_cols @ om                   # (k, r/p3) partial
+            part = jnp.matmul(h_cols, om,        # (k, r/p3) partial
+                              precision=F32)
         else:
             part = sketch_block(h_cols, cfg.seed, r_cols,
                                 row0=j * om_rows, col0=kk * r_cols,
@@ -300,8 +303,8 @@ def _sharded_rowblock_prog(cfg: StreamConfig, mesh: Mesh,
         # WRAPS negative starts (Python-style) instead of clamping, which
         # would alias the zero pad onto real dY rows for shards left of
         # the slab.  The fold itself is backend-dispatched
-        # (kernels/local.py fold_rows_block): the pallas body keeps the
-        # padded frame in VMEM and aliases the Y shard in-place.
+        # (kernels/local.py fold_rows_block): the pallas body DMAs each
+        # Y block's slab window and aliases the Y shard in-place.
         g0 = (i * p2 + j) * y_rows
         start = jnp.clip(g0 - row0 + y_rows, 0, k + y_rows)
         y_new = fold_rows_block(y_blk, dY, start, backend=backend)
@@ -309,7 +312,8 @@ def _sharded_rowblock_prog(cfg: StreamConfig, mesh: Mesh,
             return y_new
         if backend == "jnp":
             psi_c = psi_cols(cfg, row0, k)       # (k, l), traced row0
-            w_new = w_blk + psi_c.T.astype(h_blk.dtype) @ h_blk
+            w_new = w_blk + jnp.matmul(psi_c.T.astype(h_blk.dtype), h_blk,
+                                       precision=F32)
         else:
             # fused accumulate: W += Psi[:, row0:row0+k] · H in one pass
             w_new = sketch_t_block(h_blk, cfg.seed, cfg.sketch_l,
@@ -319,7 +323,7 @@ def _sharded_rowblock_prog(cfg: StreamConfig, mesh: Mesh,
         return y_new, w_new
 
     in_h = P(None, (ax2, ax3))
-    kw = {} if backend == "jnp" else {"check_rep": False}
+    kw = {} if backend == "jnp" else {"check_vma": False}
     if cfg.corange:
         fn = shard_map(body, mesh=mesh,
                        in_specs=(P((ax1, ax2), ax3), in_h, in_h, P()),

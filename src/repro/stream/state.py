@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.sketch import (SPARSE_KINDS, omega_tile, seed_keys,
+from repro.core.sketch import (F32, SPARSE_KINDS, omega_tile, seed_keys,
                                sparse_omega_rows, validate_kind)
 
 OMEGA_SALT = 0   # salt stream for Omega (range sketch)
@@ -213,7 +213,7 @@ def nystrom_local(Y, cfg: StreamConfig):
     second pass over A — it is computable from the sketch alone."""
     om = omega_tile(cfg.seed, 0, 0, cfg.n2, cfg.r, cfg.kind, Y.dtype,
                     salt=cfg.omega_salt)
-    return Y, om.T @ Y
+    return Y, jnp.matmul(om.T, Y, precision=F32)
 
 
 @functools.lru_cache(maxsize=4096)
@@ -225,6 +225,20 @@ def _local_sig(cfg: StreamConfig) -> Tuple:
             cfg.omega_salt, cfg.psi_salt)
 
 
+def _slab_times_omega(H, om):
+    """``H @ om`` for a (k, n2) row slab.  A one-row slab is summed as an
+    explicit multiply-reduce: XLA:CPU lowers a one-row matmul through a
+    gemv whose summation order differs from the batched dot of a vmapped
+    ragged lane, while the reduce lowers the same way in both, so the
+    solo update and the lane stay bitwise equal.  It sums in f32, as the
+    matmul does."""
+    if H.shape[0] == 1:
+        f32 = jnp.float32
+        return jnp.sum(H.T.astype(f32) * om.astype(f32), axis=0,
+                       keepdims=True).astype(H.dtype)
+    return jnp.matmul(H, om, precision=F32)
+
+
 def _local_rowblock_update(sig: Tuple, k: int):
     """The pure local row-block update (shared single-stream/batched)."""
     n1, n2, r, l, kind, dtype_name, corange, omega_salt, psi_salt = sig
@@ -232,13 +246,13 @@ def _local_rowblock_update(sig: Tuple, k: int):
 
     def upd(Y, W, H, keys, row0):
         om = omega_tile(keys, 0, 0, n2, r, kind, dtype, salt=omega_salt)
-        dY = H @ om                                   # full contraction
+        dY = _slab_times_omega(H, om)                 # full contraction
         Yk = jax.lax.dynamic_slice(Y, (row0, 0), (k, r))
         Y = jax.lax.dynamic_update_slice(Y, Yk + dY, (row0, 0))
         if corange:
             psi_c = omega_tile(keys, row0, 0, k, l, kind, dtype,
                                salt=psi_salt, n_total=n1)  # (k, l)
-            W = W + psi_c.T @ H
+            W = W + jnp.matmul(psi_c.T, H, precision=F32)
         return Y, W
 
     return upd
@@ -305,9 +319,9 @@ def _local_ragged_update(sig: Tuple, kb: int, backend: str = "jnp"):
     regenerated Omega/Psi tiles), which is what makes lane i of a bucketed
     batch bitwise the result of updating stream i alone (pinned by
     tests/test_service_scale.py).  ``backend`` dispatches the fold body
-    (kernels/local.py): the pallas fold keeps the padded frame in VMEM
-    and aliases Y in-place; both backends run the same ops on the same
-    operands, so the fold is bitwise across backends.
+    (kernels/local.py): the pallas fold never builds the padded frame
+    and aliases Y in-place; both backends add the same slab rows to the
+    same Y rows, so the fold is bitwise across backends.
     """
     from repro.kernels.local import fold_rows_block
     n1, n2, r, l, kind, dtype_name, corange, omega_salt, psi_salt = sig
@@ -317,7 +331,7 @@ def _local_ragged_update(sig: Tuple, kb: int, backend: str = "jnp"):
         rows = jax.lax.broadcasted_iota(jnp.int32, (kb, 1), 0)
         Hm = jnp.where(rows < kvalid, H, jnp.zeros_like(H))
         om = omega_tile(keys, 0, 0, n2, r, kind, dtype, salt=omega_salt)
-        dY = Hm @ om                                  # full contraction
+        dY = _slab_times_omega(Hm, om)                # full contraction
         start = jnp.int32(n1) - jnp.asarray(row0, jnp.int32)
         Y = fold_rows_block(Y, dY, start, backend=backend, nvalid=kvalid)
         if corange:
@@ -326,7 +340,7 @@ def _local_ragged_update(sig: Tuple, kb: int, backend: str = "jnp"):
             # so they contribute exact ±0 terms only
             psi_c = omega_tile(keys, row0, 0, kb, l, kind, dtype,
                                salt=psi_salt, n_total=n1)  # (kb, l)
-            W = W + psi_c.T @ Hm
+            W = W + jnp.matmul(psi_c.T, Hm, precision=F32)
         return Y, W
 
     return upd
@@ -478,7 +492,7 @@ class StreamingSketch:
         if self.backend == "xla":
             om = omega_tile(cfg.seed, 0, 0, cfg.n2, cfg.r, cfg.kind,
                             H.dtype, salt=cfg.omega_salt)
-            return H @ om
+            return jnp.matmul(H, om, precision=F32)
         from repro.kernels.ops import sketch_matmul
         return sketch_matmul(H, seed=cfg.seed, r=cfg.r, kind=cfg.kind,
                              salt=cfg.omega_salt,
@@ -500,7 +514,8 @@ class StreamingSketch:
             k = H.shape[0]
             self.Y = self.Y.at[row0:row0 + k, :].add(self._range_delta(H))
             if self.W is not None:
-                self.W = self.W + psi_cols(cfg, row0, k).T @ H
+                self.W = self.W + jnp.matmul(psi_cols(cfg, row0, k).T, H,
+                                             precision=F32)
         self.num_updates += 1
         return self
 
@@ -536,9 +551,10 @@ class StreamingSketch:
         om_rows = omega_tile(cfg.seed, col0, 0, k, cfg.r, cfg.kind,
                              H.dtype, salt=cfg.omega_salt,
                              n_total=cfg.n2)                 # Omega[col0:,:]
-        self.Y = self.Y + H @ om_rows
+        self.Y = self.Y + jnp.matmul(H, om_rows, precision=F32)
         if self.W is not None:
-            self.W = self.W.at[:, col0:col0 + k].add(psi_matrix(cfg) @ H)
+            self.W = self.W.at[:, col0:col0 + k].add(
+                jnp.matmul(psi_matrix(cfg), H, precision=F32))
         self.num_updates += 1
         return self
 

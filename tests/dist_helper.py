@@ -12,12 +12,16 @@ import subprocess
 import sys
 
 SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+TESTS = str(pathlib.Path(__file__).resolve().parent)
 
 
 def run_distributed(code: str, ndev: int = 8, timeout: int = 600) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    # src for the package, tests for the shared assertion helpers
+    # (f32_bounds)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [SRC, TESTS] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=timeout)

@@ -725,6 +725,35 @@ def test_worker_died_fast_fail_and_idempotent_shutdown():
 # ---------------------------------------------------------------------------
 
 
+def test_chaos_shrink_restore_in_process():
+    """The shrink-restore drill reshards on the calling process's own
+    devices (8 -> 4 -> 8 fake devices here, 4 -> 2 -> 4 on a 2x2 host)
+    and spawns nothing: its finalize is bitwise the never-resized run."""
+    out = run_distributed(r"""
+from repro.stream import faults
+res = faults.run_chaos_scenario("shrink-restore", verbose=False)
+assert res["recovered"] and res["devices"] == 8, res
+print("OK")
+""")
+    assert "OK" in out
+
+
+def test_chaos_shrink_restore_not_run_on_one_device():
+    """On one device (one CPU device, one chip) the drill cannot shrink:
+    it reports itself not run, and ``--chaos all`` stays green."""
+    import argparse
+
+    import jax
+
+    from repro.launch.serve import run_chaos
+    assert len(jax.devices()) == 1
+    res = faults.run_chaos_scenario("shrink-restore", verbose=False)
+    assert res["recovered"] is None and "even device count" in res["skipped"]
+    out = run_chaos(argparse.Namespace(chaos="shrink-restore", streams=2,
+                                       updates=1))
+    assert out["shrink-restore"]["skipped"]
+
+
 @pytest.mark.parametrize("scenario", ["torn-write", "eviction-storm"])
 def test_chaos_scenarios_recover(scenario, tmp_path):
     out = faults.run_chaos_scenario(scenario, n1=64, n2=32, r=4, streams=3,

@@ -3,9 +3,11 @@
 Pins the tentpole contract of the zero-Omega-HBM work:
 
   (a) interpret-mode Pallas vs jnp **bitwise** parity for ``sketch_block``
-      / ``sketch_t_block`` across all three omega kinds, nonzero
-      row0/col0 offsets, bf16 inputs with f32 accumulation, non-divisible
-      shapes, and the fused ``acc`` accumulation;
+      across all three omega kinds, nonzero row0/col0 offsets, bf16
+      inputs with f32 accumulation, non-divisible shapes, and the fused
+      ``acc`` accumulation; ``sketch_t_block`` to the f32 summation-order
+      bound (tests/f32_bounds.py) where XLA:CPU orders its transposed dot
+      differently;
   (b) ``backend="auto"`` never changes numerics (property test);
   (c) every distributed path (Alg. 1 grids, both Nyström 1-D variants,
       the general and bound-driven two-grid forms, the sharded streaming
@@ -69,18 +71,30 @@ def test_sketch_block_backend_parity(kind, off):
 @pytest.mark.parametrize("kind", KINDS)
 @pytest.mark.parametrize("off", OFFSETS)
 def test_sketch_t_block_backend_parity(kind, off):
+    """Omega^T·B on both backends agrees to the f32 summation-order bound.
+    Not bitwise: XLA:CPU runs the jnp body's transposed-operand dot in a
+    different reduction order than the kernel's in-interpreter dot (the
+    Omega entries themselves are bitwise — test_rng pins them)."""
+    from f32_bounds import assert_orders_agree, gemm_diff_bound
+    from repro.core.sketch import omega_tile
     B = jax.random.normal(jax.random.key(2), (48, 16))
     r0, c0 = off
     j = sketch_t_block(B, 7, 8, row0=r0, col0=c0, kind=kind, salt=1,
                        backend="jnp")
     p = sketch_t_block(B, 7, 8, row0=r0, col0=c0, kind=kind, salt=1,
                        backend="pallas")
-    np.testing.assert_array_equal(np.asarray(j), np.asarray(p))
+    om = omega_tile(7, r0, c0, 48, 8, kind, salt=1)
+    assert_orders_agree(p, j, gemm_diff_bound(om.T, B), kind)
 
 
 def test_fused_acc_parity_and_semantics():
     """sketch_block(acc=Y) == Y + sketch_block() on both backends, bitwise
-    — the fused accumulator adds in the same order as the jnp body."""
+    — the fused accumulator adds in the same order as the jnp body.  The
+    transposed form is bitwise on the jnp backend (the same program) and
+    within the f32 summation-order bound on the pallas backend (see
+    test_sketch_t_block_backend_parity)."""
+    from f32_bounds import assert_orders_agree, gemm_diff_bound
+    from repro.core.sketch import omega_tile
     A = jax.random.normal(jax.random.key(0), (16, 48))
     Y = jax.random.normal(jax.random.key(1), (16, 8))
     base = Y + sketch_block(A, 7, 8, backend="jnp")
@@ -90,9 +104,12 @@ def test_fused_acc_parity_and_semantics():
     W = jax.random.normal(jax.random.key(3), (8, 16))
     B = jax.random.normal(jax.random.key(2), (48, 16))
     tbase = W + sketch_t_block(B, 7, 8, backend="jnp")
-    for backend in ("jnp", "pallas"):
-        got = sketch_t_block(B, 7, 8, acc=W, backend=backend)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(tbase))
+    got = sketch_t_block(B, 7, 8, acc=W, backend="jnp")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(tbase))
+    got = sketch_t_block(B, 7, 8, acc=W, backend="pallas")
+    om = omega_tile(7, 0, 0, 48, 8)
+    assert_orders_agree(got, tbase, gemm_diff_bound(om.T, B, acc=W),
+                        "acc + Omega^T B")
 
 
 def test_bf16_inputs_f32_accumulation_parity():
@@ -140,12 +157,42 @@ def test_k_split_blocks_tolerance():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("split", [False, True], ids=["one-k-tile",
+                                                      "k-split"])
+def test_omega_fill_slices_bitwise(kind, split):
+    """A kernel Omega tile larger than one generator slice
+    (``_GEN_ENTRIES``) is filled by the in-kernel slice loop the chip
+    runs, at global rows ``row0 + k-tile offset + slice start``.  With an
+    identity operand every output entry is one product 1·omega plus exact
+    zeros, so both kernels return their Omega tiles in any summation
+    order: bitwise ``omega_tile`` (and the jnp backend) at the same
+    coordinates, across k tiles and slices."""
+    from repro.core.sketch import omega_tile
+    from repro.kernels.local import _GEN_ENTRIES, gen_rows
+    k, cols, r0, c0 = 1024, 32, 40, 3
+    eye = jnp.eye(k, dtype=jnp.float32)
+    om = np.asarray(omega_tile(7, r0, c0, k, cols, kind))
+    fwd = (512, cols, 512) if split else None
+    tb = (cols, 512, 512) if split else None
+    bk = 512 if split else k
+    assert bk * cols > _GEN_ENTRIES and gen_rows(bk, cols) < bk
+    for backend, blocks in (("jnp", None), ("pallas", fwd)):
+        got = sketch_block(eye, 7, cols, row0=r0, col0=c0, kind=kind,
+                           backend=backend, blocks=blocks)
+        np.testing.assert_array_equal(np.asarray(got), om, err_msg=backend)
+    for backend, blocks in (("jnp", None), ("pallas", tb)):
+        got = sketch_t_block(eye, 7, cols, row0=r0, col0=c0, kind=kind,
+                             backend=backend, blocks=blocks)
+        np.testing.assert_array_equal(np.asarray(got), om.T, err_msg=backend)
+
+
 def test_fold_rows_block_backend_parity():
     """The row-slab Y fold (stream ``update_rows``) is backend-dispatched
-    (``fold_rows_block``): the pallas body runs the identical zero-pad +
-    traced-offset slice + add inside one kernel (padded frame in VMEM, Y
-    aliased in-place) and must be BITWISE the jnp body across in-range,
-    clipped-left, clipped-right, and fully-out-of-overlap offsets."""
+    (``fold_rows_block``): the pallas body adds the same slab rows to the
+    same Y rows (slab windows DMA'd per Y block, Y aliased in-place) and
+    must be BITWISE the jnp body across in-range, clipped-left,
+    clipped-right, and fully-out-of-overlap offsets."""
     from repro.kernels.local import fold_rows_block
     y = jax.random.normal(jax.random.key(0), (8, 6))
     d = jax.random.normal(jax.random.key(1), (5, 6))
@@ -175,21 +222,23 @@ def test_fold_rows_block_backend_parity():
 
 
 def test_fold_rows_block_padded_path_parity():
-    """The native-TPU tiling pads the fold frame to (8, 128)-aligned
-    shapes; the in-kernel top pad is then TALLER than the logical shard,
-    so the traced start must be shifted by (mp - m) or the slab delta
-    lands rows too low.  Forced through interpret mode so CI pins the
-    padding contract the compiled path relies on (padding never shifts
-    in-range placement)."""
+    """The native-TPU fold grids Y in row blocks (a ragged last block
+    included) and DMAs each block's slab window from a frame padded by
+    one block a side, clipping the window start.  Forced through
+    interpret mode with 8-row blocks so CI pins the clipping contract the
+    compiled path relies on: every start in [0, k + m], masked and not,
+    is bitwise the jnp fold."""
     from repro.kernels.local import _fold_rows_jnp, _fold_rows_pallas
-    y = jax.random.normal(jax.random.key(0), (6, 6))
+    y = jax.random.normal(jax.random.key(0), (13, 6))
     d = jax.random.normal(jax.random.key(1), (5, 6))
-    for start in (0, 2, 6, 11):       # clip range is [0, k + m]
-        ref = _fold_rows_jnp(y, d, jnp.int32(start))
-        got = _fold_rows_pallas(y, d, jnp.int32(start), interpret=True,
-                                pad_to=(8, 128, 8))
-        np.testing.assert_array_equal(np.asarray(ref), np.asarray(got),
-                                      err_msg=f"start={start}")
+    for start in range(0, 19):        # clip range is [0, k + m]
+        for nvalid in (None, 0, 3):
+            ref = _fold_rows_jnp(y, d, jnp.int32(start), nvalid=nvalid)
+            got = _fold_rows_pallas(y, d, jnp.int32(start), interpret=True,
+                                    nvalid=nvalid, block_rows=8)
+            np.testing.assert_array_equal(
+                np.asarray(ref), np.asarray(got),
+                err_msg=f"start={start} nvalid={nvalid}")
 
 
 def test_traced_seed_and_offsets_under_jit():
@@ -262,14 +311,19 @@ for fn in (nystrom_no_redist, nystrom_redist):
     assert np.array_equal(np.asarray(Bj), np.asarray(Bp)), fn
     assert np.array_equal(np.asarray(Cj), np.asarray(Cp)), fn
 
-# §5.3 bound-driven two-grid: the bitwise-safe pair (p2==1, q1==1) stays
-# bitwise vs the single-device reference on BOTH backends
+# §5.3 bound-driven two-grid: the pair that splits no contraction
+# (p2==1, q1==1) is bitwise across backends, and agrees with the
+# single-device reference to the f32 summation-order bound (a per-shard
+# GEMM and the whole-matrix GEMM add in different orders on XLA:CPU)
+from f32_bounds import assert_orders_agree, nystrom_diff_bounds
+from repro.core.sketch import omega_tile
 Bj, Cj = nystrom_two_grid(S, 5, rr, p=(8,1,1), q=(1,1,8), backend="jnp")
 Bp, Cp = nystrom_two_grid(S, 5, rr, p=(8,1,1), q=(1,1,8), backend="pallas")
 assert np.array_equal(np.asarray(Bj), np.asarray(Bp))
 assert np.array_equal(np.asarray(Cj), np.asarray(Cp))
-assert np.array_equal(np.asarray(Bp), np.asarray(Bref))
-assert np.array_equal(np.asarray(Cp), np.asarray(Cref))
+dB, dC = nystrom_diff_bounds(S, omega_tile(5, 0, 0, n, rr))
+assert_orders_agree(Bp, Bref, dB, "B two-grid vs reference")
+assert_orders_agree(Cp, Cref, dC, "C two-grid vs reference")
 
 # one-mesh general two-grid
 mesh2 = make_grid_mesh(2, 2, 2)

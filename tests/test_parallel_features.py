@@ -236,17 +236,17 @@ print("OK")
 
 
 def test_compat_vmem_scratch_probe():
-    """The pallas-TPU VMEM probe lives in core/compat.py behind an explicit
-    jax-version check (no dead try/except fallback).  This file is part of
-    the jax-floor CI shard, so the probe is exercised on the minimum
-    supported jax on every PR: importing repro.core runs the import-time
-    probe, and the allocation below runs the accessor."""
+    """core/compat.py is two plain aliases for the one installed JAX:
+    ``shard_map`` IS ``jax.shard_map`` and ``vmem_scratch`` IS
+    ``pltpu.VMEM`` — no version probe, no fallback.  The allocation below
+    must round-trip its shape for pallas_call scratch_shapes."""
+    import jax
     import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
 
     from repro.core import compat
 
-    assert compat.JAX_VERSION >= (0, 4, 30), compat.JAX_VERSION
+    assert compat.shard_map is jax.shard_map
+    assert compat.vmem_scratch is pltpu.VMEM
     scratch = compat.vmem_scratch((8, 128), jnp.float32)
-    # pltpu.VMEM yields a memory-space-tagged scratch allocation usable in
-    # pallas_call scratch_shapes; shape must round-trip.
     assert tuple(scratch.shape) == (8, 128)

@@ -187,6 +187,22 @@ def test_infeasible_shape_yields_analytic_only_plan():
 # (d) execute == direct call (single device; multi-device in subprocess)
 # ---------------------------------------------------------------------------
 
+def test_probe_machine_maps_known_devices_and_refuses_others():
+    """Known device kinds and the CPU map to their presets; any other
+    device raises instead of borrowing another chip's peaks."""
+    from types import SimpleNamespace
+    from repro.plan import PRESETS, probe_machine
+    dev = lambda platform, kind: SimpleNamespace(platform=platform,
+                                                 device_kind=kind)
+    assert probe_machine(dev("tpu", "TPU v5 lite")) is PRESETS["tpu_v5e"]
+    assert probe_machine(dev("tpu", "TPU v4")) is PRESETS["tpu_v4"]
+    assert probe_machine(dev("cpu", "cpu")) is PRESETS["cpu"]
+    for d in (dev("tpu", "TPU v6 lite"), dev("gpu", "NVIDIA H100")):
+        with pytest.raises(ValueError, match="no machine preset"):
+            probe_machine(d)
+    assert probe_machine() is PRESETS["cpu"]    # this process: CPU
+
+
 def test_execute_local_bitwise():
     n1, n2, r, seed = 48, 64, 8, 11
     A = jax.random.normal(jax.random.key(0), (n1, n2))

@@ -1,0 +1,122 @@
+"""The Pallas kernels compile for a TPU v5e, at the chip smoke's widths.
+
+Interpret mode on the CPU cannot see what the TPU compiler refuses: VMEM
+over the scoped limit, an unaligned dynamic slice, an unsupported cast.
+These tests compile each kernel of the main path for one chip of a
+described (not attached) ``v5e:2x2`` topology and assert that the compiled
+program calls the Mosaic kernel (``tpu_custom_call``).  Nothing runs.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library,
+and the test workers import every test file.  The fixture skips when no
+topology can be described (no TPU compiler installed) and turns JAX's
+persistent compilation cache off, since a program compiled for a described
+chip is written to it but cannot be read back without one.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.kernels import local, ops
+
+# the chip smoke's per-chip widths (chip_smoke.py)
+N, R = 32768, 256                  # dense sketch / Nyström: A is N x N
+N1, N2, R_S, L_S = 4096, 768, 48, 97   # one stream tenant (l = 2r + 1)
+LANES, KB = 64, 64                 # a full ragged bucket
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                 # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shape(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_cases():
+    """name -> (fn, argument shapes); every kernel call native
+    (``interpret=False``)."""
+    def sketch(kind, acc):
+        def fn(a, *y):
+            return local.sketch_block(a, 0, R, kind=kind, backend="pallas",
+                                      acc=y[0] if acc else None,
+                                      interpret=False)
+        args = [((N, N), jnp.float32)] + ([((N, R), jnp.float32)]
+                                          if acc else [])
+        return fn, args
+
+    def fold(y, d, s, n):
+        return local.fold_rows_block(y, d, s, backend="pallas", nvalid=n,
+                                     interpret=False)
+
+    i32 = jnp.int32
+    return {
+        "sketch_block-normal": sketch("normal", False),
+        "sketch_block-normal-acc": sketch("normal", True),
+        "sketch_block-uniform": sketch("uniform", False),
+        "sketch_block-uniform-acc": sketch("uniform", True),
+        # an Omega tile of one generator slice: the fill loop runs once
+        "sketch_block-one-slice": (
+            lambda a: local.sketch_block(a, 0, R_S, backend="pallas",
+                                         interpret=False),
+            [((KB, 64), jnp.float32)]),
+        "sketch_t_block": (
+            lambda b: local.sketch_t_block(b, 0, R, backend="pallas",
+                                           interpret=False),
+            [((N, R), jnp.float32)]),
+        "gemm_block-acc": (
+            lambda a, b, y: local.gemm_block(a, b, acc=y, alpha=-1.0,
+                                             backend="pallas",
+                                             interpret=False),
+            [((N1, R_S), jnp.float32), ((R_S, N2), jnp.float32),
+             ((N1, N2), jnp.float32)]),
+        "fold_rows_block-masked": (
+            fold, [((N1, R_S), jnp.float32), ((KB, R_S), jnp.float32),
+                   ((), i32), ((), i32)]),
+        "fold_rows_block-vmapped": (
+            jax.vmap(fold),
+            [((LANES, N1, R_S), jnp.float32),
+             ((LANES, KB, R_S), jnp.float32), ((LANES,), i32),
+             ((LANES,), i32)]),
+        "ops.sketch_matmul": (
+            lambda a: ops.sketch_matmul(a, seed=0, r=R),
+            [((N, N), jnp.float32)]),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_kernel_cases()))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, args = _kernel_cases()[name]
+    shapes = [_shape(one_chip, s, dt) for s, dt in args]
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text, name
+
+
+def test_default_blocks_fit_the_scoped_vmem():
+    """The native block policy's tiles fit the budget by the same model
+    the autotuner filters with, and the budget sits under the limit the
+    kernels ask Mosaic for."""
+    assert local.VMEM_BUDGET <= local.VMEM_LIMIT
+    for m, n, k in ((N, R, N), (R, R, N), (N1, R_S, N2), (KB, R_S, N2)):
+        bm, bn, bk = local.default_local_blocks(m, n, k, interpret=False)
+        assert local.vmem_fit_bytes(bm, bn, bk) <= local.VMEM_BUDGET
+        sk = local.gen_rows(bk, max(bm, bn))
+        assert bk % sk == 0 and sk % 8 == 0

@@ -174,15 +174,23 @@ assert len(jax.devices()) == 8
 seed, n, r = 5, 64, 16
 X = jax.random.normal(jax.random.key(2), (n, 8)); S = X @ X.T
 Bref, Cref = nystrom_reference(S, seed, r)
+# against the single-device reference the contract is the f32
+# summation-order bound (tests/f32_bounds.py): a per-shard GEMM and the
+# whole-matrix GEMM add the same products in different orders on XLA:CPU
+from f32_bounds import (assert_orders_agree, gemm_diff_bound,
+                        nystrom_diff_bounds)
+from repro.core.sketch import omega_tile
+om = omega_tile(seed, 0, 0, n, r)
+dB, dC = nystrom_diff_bounds(S, om)
 
-# (p, q) pairs that never split a contraction (p2 == 1, q1 == 1) are
-# bitwise vs the single-device reference — including p != q pairs that
-# nystrom_general's shared-axis mesh cannot express.
+# (p, q) pairs that never split a contraction (p2 == 1, q1 == 1) match
+# the single-device reference to the summation-order bound — including
+# p != q pairs that nystrom_general's shared-axis mesh cannot express.
 for (p, q) in [((8,1,1), (1,1,8)), ((8,1,1), (1,2,4)), ((4,1,2), (1,4,2)),
                ((8,1,1), (1,4,2)), ((2,1,4), (1,8,1))]:
     B, C = nystrom_two_grid(S, seed, r, p=p, q=q)
-    assert np.array_equal(np.asarray(B), np.asarray(Bref)), (p, q)
-    assert np.array_equal(np.asarray(C), np.asarray(Cref)), (p, q)
+    assert_orders_agree(B, Bref, dB, f"B {(p, q)}")
+    assert_orders_agree(C, Cref, dC, f"C {(p, q)}")
 print("OK bitwise-safe pairs")
 
 # split-contraction pairs (p2 > 1 or q1 > 1) reorder partial sums: close,
@@ -194,19 +202,19 @@ for (p, q) in [((8,1,1), (2,1,4)), ((2,2,2), (4,2,1)), ((1,2,4), (2,2,2))]:
 print("OK split pairs close")
 
 # acceptance: an executable=True alg2_bound_driven candidate whose
-# Plan.execute is bitwise nystrom_reference with p != q (regime-1 ideal
+# Plan.execute matches nystrom_reference with p != q (regime-1 ideal
 # grids p=(8,1,1), q=(1,1,8) keep both contractions whole)...
 pn = plan_nystrom(n, r, P=8, machine=CPU, variant="bound_driven")
 assert pn.variant == "alg2_bound_driven" and pn.executable
 assert pn.grid != pn.q_grid, (pn.grid, pn.q_grid)
 B, C = pn.execute(S, seed=seed)
-assert np.array_equal(np.asarray(B), np.asarray(Bref))
-assert np.array_equal(np.asarray(C), np.asarray(Cref))
+assert_orders_agree(B, Bref, dB, "plan B")
+assert_orders_agree(C, Cref, dC, "plan C")
 # ...and Plan.execute IS the direct call
 Bd, Cd = nystrom_two_grid(S, seed, r, p=pn.grid, q=pn.q_grid)
 assert np.array_equal(np.asarray(B), np.asarray(Bd))
 assert np.array_equal(np.asarray(C), np.asarray(Cd))
-print("OK plan bound_driven bitwise vs reference and direct call")
+print("OK plan bound_driven vs reference, bitwise vs direct call")
 
 # regime 2 (r < P): a genuinely two-grid pair q=(2,1,4) the 1-D variants
 # cannot run at all (r % P != 0); the single-jit fused form wins in auto
@@ -226,15 +234,15 @@ print("OK regime-2 bound_driven execute == direct")
 # nystrom_auto dispatches both the explicit variant and a bound-driven plan
 Ba, Ca, mesh_q, v = nystrom_auto(S, seed, r, variant="bound_driven")
 assert v == "bound_driven"
-assert np.array_equal(np.asarray(Ca), np.asarray(Cref))
+assert_orders_agree(Ca, Cref, dC, "auto C")
 Bp, Cp, _, vp = nystrom_auto(S, seed, r, plan=pn)
 assert vp == "bound_driven"
-assert np.array_equal(np.asarray(Cp), np.asarray(Cref))
+assert_orders_agree(Cp, Cref, dC, "auto plan C")
 print("OK nystrom_auto bound_driven")
 
 # the second stage alone consumes any row-sharded B (streaming finalize)
 B3, C3 = nystrom_second_stage_two_grid(Bref, seed, r, (1, 2, 4))
-assert np.array_equal(np.asarray(C3), np.asarray(Cref))
+assert_orders_agree(C3, Cref, gemm_diff_bound(om.T, Bref), "stage 2")
 print("OK standalone second stage")
 
 # streamed Y -> bound_driven finalize, vs the one-shot reference
