@@ -47,6 +47,10 @@ HBM roofline (the point): per local GEMM the jnp backend touches
 accumulation); the fused backend touches ``m·k + m·n`` — the ``k·n``
 Omega stream never exists.  ``plan.model`` prices both so the planner
 picks the backend analytically.
+
+Profiles: each ``pallas_call`` is named after its entry point
+(``sketch_block``, ``sketch_t_block``, ``gemm_block``,
+``fold_rows_block``), which is the device op's name in a profile.
 """
 from __future__ import annotations
 
@@ -372,7 +376,7 @@ def _sketch_block_pallas(A, seed, cols, row0, col0, kind, salt, scale,
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         input_output_aliases=aliases,
         compiler_params=_compiler_params(interpret),
-        interpret=interpret)(*operands)
+        interpret=interpret, name="sketch_block")(*operands)
     return out[:m, :cols]
 
 
@@ -410,7 +414,7 @@ def _sketch_t_block_pallas(B, seed, cols, row0, col0, kind, salt, scale,
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         input_output_aliases=aliases,
         compiler_params=_compiler_params(interpret),
-        interpret=interpret)(*operands)
+        interpret=interpret, name="sketch_t_block")(*operands)
     return out[:cols, :r2]
 
 
@@ -512,7 +516,7 @@ def _gemm_pallas(A, B, alpha, acc, out_dtype, blocks, interpret):
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         input_output_aliases=aliases,
         compiler_params=_compiler_params(interpret),
-        interpret=interpret)(*operands)
+        interpret=interpret, name="gemm_block")(*operands)
     return out[:m, :n]
 
 
@@ -606,7 +610,7 @@ def _fold_rows_pallas(y, d, start, interpret, nvalid=None, block_rows=None):
         out_shape=jax.ShapeDtypeStruct((m, c), y.dtype),
         input_output_aliases={1: 0},    # y aliases the output in-place
         compiler_params=_compiler_params(interpret),
-        interpret=interpret)(meta, y, dp)
+        interpret=interpret, name="fold_rows_block")(meta, y, dp)
 
 
 def fold_rows_block(y, d, start, backend: str = "jnp", interpret=None,

@@ -7,11 +7,12 @@ and is consumed immediately by the MXU accumulation.  HBM traffic drops from
 ``n1*n2 + n2*r + n1*r`` words (classic GEMM) to ``n1*n2 + n1*r`` — the
 memory-roofline analogue of the paper's zero-communication claim.
 
-Kernels:
-  * ``sketch_matmul_kernel``    — B = A @ Omega          (A: n1 x n2)
-  * ``sketch_t_matmul_kernel``  — C = Omega^T @ B        (B: n x r2)
-  * ``gen_omega_kernel``        — materialize an Omega tile (bitwise oracle
-                                  check for the in-kernel generator)
+Kernels (each ``pallas_call`` is named, and the name is the device op's
+name in a profile):
+  * ``sketch_a_omega``    — B = A @ Omega          (A: n1 x n2)
+  * ``sketch_omega_t_b``  — C = Omega^T @ B        (B: n x r2)
+  * ``gen_omega``         — materialize an Omega tile (bitwise oracle
+                            check for the in-kernel generator)
 
 Tiling: grid (n1/bm, r/bn, n2/bk) with the contraction dim innermost; an
 f32 VMEM scratch accumulates across k-steps so inputs/outputs may be bf16.
@@ -96,6 +97,7 @@ def sketch_matmul_pallas(A, seed: int, r: int, *,
         out_shape=jax.ShapeDtypeStruct((n1, r), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="sketch_a_omega",
     )(A)
 
 
@@ -144,6 +146,7 @@ def sketch_t_matmul_pallas(B, seed: int, r: int, *,
         out_shape=jax.ShapeDtypeStruct((r, r2), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="sketch_omega_t_b",
     )(B)
 
 
@@ -171,4 +174,5 @@ def gen_omega_pallas(seed: int, n2: int, r: int, *,
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n2, r), dtype),
         interpret=interpret,
+        name="gen_omega",
     )()

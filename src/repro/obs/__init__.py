@@ -6,9 +6,10 @@ Three instruments, one install pattern:
     counters/gauges/histograms with Prometheus text exposition.  The
     serving layer publishes into it unconditionally (the publish path is
     a dict hit + float add).
-  * **tracer** (:mod:`.trace`) — span timeline with Chrome/Perfetto
-    export; off by default (``span()`` is a shared no-op until
-    ``install_tracer``).
+  * **tracer** (:mod:`.trace`) — program spans: annotations in any
+    active ``jax.profiler`` trace, and a span timeline with
+    Chrome/Perfetto export once ``install_tracer`` is called; with
+    neither, ``span()`` is a shared no-op.
   * **ledger** (:mod:`.ledger`) — per-call-site measured collective bytes
     vs planner prediction vs the Theorem-2/3 floor; off by default
     (``install_ledger``).  ``report.honesty_report`` renders the audit;

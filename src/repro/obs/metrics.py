@@ -155,6 +155,10 @@ class Histogram(_Metric):
         st = self._states.get(_labelkey(labels))
         return 0 if st is None else st.count
 
+    def labelsets(self):
+        """The label sets observed so far, as dicts."""
+        return [dict(k) for k in sorted(self._states)]
+
     def percentile(self, q: float, **labels) -> float:
         """Exact q-th percentile over the retained raw-value window
         (0.0 on an empty window — never an exception)."""

@@ -1,9 +1,20 @@
-"""Thread-safe span tracer with Chrome/Perfetto ``trace_event`` export.
+"""Program spans: on the JAX profiler's clock, and in a span tracer with
+Chrome/Perfetto ``trace_event`` export.
 
 One serving run produces a timeline of ingest -> bucket -> fused update ->
 finalize: every instrumented path opens spans through the module-level
-:func:`span` helper, which is a shared no-op context manager while no
-tracer is installed — the uninstrumented hot path pays one global read.
+:func:`span` helper.  A span goes to two places:
+
+  * **the JAX profiler** — while a profiler session is active
+    (``jax.profiler.start_trace`` or ``jax.profiler.trace``), the span is a
+    ``jax.profiler.TraceAnnotation`` on the calling thread, so it lands on
+    the profile's host plane beside the device's operations, on the same
+    clock;
+  * **the installed** :class:`Tracer` — a :class:`SpanRecord` in memory.
+
+With neither, :func:`span` returns one shared no-op context manager: the
+uninstrumented hot path pays a global read and the profiler's
+``is_enabled`` check.
 
 Cross-thread parenting: spans nest per-thread via a ``threading.local``
 stack, and a span may be opened with an explicit ``parent=`` id — the
@@ -20,7 +31,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import itertools
 import json
 import threading
@@ -41,9 +51,22 @@ class SpanRecord:
     args: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
+# ``jax.profiler.TraceAnnotation``, bound on first use so that importing
+# this module does not import JAX
+_Annotation = None
+
+
+def _annotation_cls():
+    global _Annotation
+    if _Annotation is None:
+        from jax.profiler import TraceAnnotation
+        _Annotation = TraceAnnotation
+    return _Annotation
+
+
 class _SpanCtx:
     __slots__ = ("_tracer", "name", "cat", "args", "parent",
-                 "span_id", "_t0", "_explicit_parent")
+                 "span_id", "_t0", "_explicit_parent", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  parent: Optional[int], args: Dict[str, Any]):
@@ -55,8 +78,13 @@ class _SpanCtx:
         self.parent = None
         self.span_id = None
         self._t0 = 0
+        self._annotation = None
 
     def __enter__(self):
+        annotation = _Annotation or _annotation_cls()
+        if annotation.is_enabled():
+            self._annotation = annotation(self.name, **self.args)
+            self._annotation.__enter__()
         t = self._tracer
         self.span_id = next(t._ids)
         stack = t._stack()
@@ -69,6 +97,9 @@ class _SpanCtx:
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         t = self._tracer
         stack = t._stack()
         if stack and stack[-1] == self.span_id:
@@ -111,18 +142,6 @@ class Tracer:
         """Context manager opening a span; nests under the thread's current
         span unless ``parent=`` pins it explicitly (cross-thread)."""
         return _SpanCtx(self, name, cat, parent, args)
-
-    def trace(self, name: Optional[str] = None, cat: str = ""):
-        """Decorator form: ``@tracer.trace("my.op")``."""
-        def deco(fn):
-            label = name or fn.__qualname__
-
-            @functools.wraps(fn)
-            def wrapper(*a, **kw):
-                with self.span(label, cat=cat):
-                    return fn(*a, **kw)
-            return wrapper
-        return deco
 
     def current_span_id(self) -> Optional[int]:
         """Id of this thread's innermost open span (None outside spans) —
@@ -169,7 +188,7 @@ class Tracer:
 _tracer: Optional[Tracer] = None
 
 # one shared reusable no-op context manager: `with span(...)` costs a
-# global read + a function call when tracing is off
+# global read, a function call and the profiler's check when tracing is off
 _NULL = contextlib.nullcontext()
 
 
@@ -193,12 +212,18 @@ def uninstall_tracer() -> Optional[Tracer]:
 
 
 def span(name: str, cat: str = "", parent: Optional[int] = None, **args):
-    """Module-level span helper: a real span when a tracer is installed,
-    the shared no-op context manager otherwise."""
+    """Module-level span helper: a profiler annotation while a JAX profiler
+    session is active, a :class:`SpanRecord` while a tracer is installed,
+    both when both are on, and the shared no-op context manager otherwise.
+    ``args`` become the annotation's stats; ``parent=`` parents the record
+    across threads (the annotation nests on its own thread only)."""
     t = _tracer
-    if t is None:
-        return _NULL
-    return t.span(name, cat=cat, parent=parent, **args)
+    if t is not None:
+        return t.span(name, cat=cat, parent=parent, **args)
+    annotation = _Annotation or _annotation_cls()
+    if annotation.is_enabled():
+        return annotation(name, **args)
+    return _NULL
 
 
 def current_span_id() -> Optional[int]:

@@ -45,11 +45,15 @@ from repro.core.lower_bounds import (
     nystrom_regime,
 )
 from repro.core.kinds import SPARSE_KINDS
+from repro.obs.metrics import DEFAULT_BUCKETS
 
 from . import model as M
 
 # Default Pallas block sizes (MXU-aligned; kernels/sketch_matmul.py).
 DEFAULT_BLOCKS = {"bm": 256, "bn": 128, "bk": 512}
+
+# plan_execute_seconds buckets (s): a dispatch takes 10-1000 microseconds
+_EXECUTE_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4) + DEFAULT_BUCKETS
 
 
 def _dtype_name(dtype) -> str:
@@ -144,9 +148,9 @@ class Plan:
                 f"is analytic-only (no executable grid divides the shape); "
                 f"pad the shape or change P")
         from repro.obs import ledger as obs_ledger
+        from repro.obs import metrics as obs_metrics
         from repro.obs import trace as obs_trace
-        led = obs_ledger.get_ledger()
-        t0 = time.perf_counter() if led is not None else 0.0
+        t0 = time.perf_counter()
         with obs_trace.span("plan.execute", cat="plan", task=self.task,
                             variant=self.variant, dims=list(self.dims),
                             P=self.n_procs):
@@ -158,6 +162,16 @@ class Plan:
                 out = self._execute_stream(A, seed, devices)
             else:
                 raise ValueError(self.task)
+        # host time to dispatch: the entry points return before the
+        # device has finished
+        wall_s = time.perf_counter() - t0
+        reg = obs_metrics.get_metrics()
+        reg.histogram(
+            "plan_execute_seconds",
+            "host seconds in Plan.execute (dispatch, not device completion)",
+            buckets=_EXECUTE_BUCKETS).observe(
+                wall_s, task=self.task, variant=self.variant)
+        led = obs_ledger.get_ledger()
         if led is not None:
             # analytic site: execute dispatches into opaque entry points
             # (the instrumented layers below contribute the HLO-backed
@@ -169,7 +183,7 @@ class Plan:
                        lower_bound_words=self.lower_bound_words,
                        itemsize=np.dtype(self.dtype).itemsize,
                        cache_key=cache_key(self),
-                       wall_s=time.perf_counter() - t0,
+                       wall_s=wall_s,
                        detail=(self.dims, self.n_procs))
         return out
 

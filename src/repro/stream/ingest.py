@@ -160,14 +160,16 @@ class IngestQueue:
             "round survived)")
         self._m_latency = m.histogram(
             "ingest_drain_latency_seconds",
-            "submit -> applied latency through the queue")
+            "submit -> dispatched latency through the queue (stamped "
+            "when the round's update is dispatched, before the device "
+            "has applied it)")
         self._q: "queue.Queue[Tuple]" = queue.Queue(maxsize=depth)
         self._lock = threading.Lock()
         self._done = threading.Condition(self._lock)
         self._inflight: Dict[int, int] = {}
         self._closed_sids: set = set()
         self._errors: List[Tuple[int, Exception]] = []
-        self._lat: List[float] = []         # submit->applied seconds
+        self._lat: List[float] = []         # submit->dispatched seconds
         self._submitted = 0
         self._applied = 0
         self._rejected = 0

@@ -186,18 +186,6 @@ def test_span_nesting_and_ids():
     assert names["inner"].dur_ns >= 0
 
 
-def test_trace_decorator():
-    t = obs.install_tracer()
-
-    @t.trace("my.op", cat="x")
-    def f(v):
-        return v + 1
-
-    assert f(1) == 2
-    (s,) = t.spans
-    assert (s.name, s.cat) == ("my.op", "x")
-
-
 def test_chrome_export(tmp_path):
     t = obs.install_tracer()
     with obs_trace.span("a", cat="c", n=7):
@@ -245,6 +233,189 @@ def test_cross_thread_explicit_parent():
     names = {s.name: s for s in t.spans}
     assert names["apply"].parent_id == names["submit"].span_id
     assert names["apply"].tid != names["submit"].tid
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a JAX profiler session on the CPU; returns the
+    host events of the written profile as (line, name, start, end), where
+    a line is one thread's."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = []
+    for p, plane in enumerate(ProfileData.from_file(str(path)).planes):
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                for ev in line.events:
+                    events.append(((p, i), ev.name, ev.start_ns,
+                                   ev.start_ns + ev.duration_ns))
+    return events
+
+
+def _inside(inner, outer):
+    return outer[2] <= inner[2] and inner[3] <= outer[3]
+
+
+def test_spans_land_in_the_profile_with_no_tracer(tmp_path):
+    from repro.plan import plan_sketch
+    plan = plan_sketch(32, 16, 8, P=1)
+    A = np.ones((32, 16), np.float32)
+    jax.block_until_ready(plan.execute(A))          # compile outside
+    svc, sids, q = _local_service_and_queue(n_streams=1)
+
+    def work():
+        with jax.profiler.TraceAnnotation("test.request"):
+            jax.block_until_ready(plan.execute(A))
+            with q:
+                q.submit(sids[0], np.ones((4, 16), np.float32), 0)
+                q.flush(raise_errors=True)
+
+    assert obs_trace.get_tracer() is None
+    events = _profiled(tmp_path, work)
+    (outer,) = [e for e in events if e[1] == "test.request"]
+    (execute,) = [e for e in events if e[1] == "plan.execute"]
+    rounds = [e for e in events if e[1] == "ingest.apply_round"]
+    assert execute[0] == outer[0] and _inside(execute, outer)
+    assert rounds and all(_inside(e, outer) for e in rounds)
+    assert all(e[0] != outer[0] for e in rounds)    # the worker's thread
+
+
+def test_span_is_recorded_and_profiled_with_a_tracer(tmp_path):
+    t = obs.install_tracer()
+
+    def work():
+        with obs_trace.span("outer.op", cat="t", n=3):
+            with obs_trace.span("inner.op"):
+                pass
+
+    events = _profiled(tmp_path, work)
+    assert [s.name for s in t.spans] == ["inner.op", "outer.op"]
+    assert t.spans[1].args == {"n": 3}
+    (outer,) = [e for e in events if e[1] == "outer.op"]
+    (inner,) = [e for e in events if e[1] == "inner.op"]
+    assert _inside(inner, outer)
+
+
+def test_plan_execute_observes_its_host_time():
+    from repro.plan import plan_sketch
+    plan = plan_sketch(32, 16, 8, P=1)
+    labels = {"task": "sketch", "variant": plan.variant}
+    with fresh_metrics() as reg:
+        for calls in (1, 2, 3):
+            plan.execute(np.ones((32, 16), np.float32))
+            hist = reg.histogram("plan_execute_seconds")
+            assert hist.count(**labels) == calls
+        assert hist.labelsets() == [labels]
+        assert hist.percentile(50, **labels) > 0
+        assert "plan_execute_seconds_count" in reg.prometheus_text()
+
+
+def test_omega_counters_match_the_kernel_grid():
+    """Generated entries are the fused kernel's grid steps times its
+    Omega tile; needed are the n2·r entries the product uses.  Three row
+    blocks of 256 (n1 = 520, padded to 768) generate Omega three times."""
+    from repro.kernels.ops import sketch_matmul
+    from repro.plan import plan_sketch
+    n1, n2, r = 520, 96, 16
+    plan = plan_sketch(n1, n2, r, P=1, allow_pallas=True)
+    assert plan.variant == "pallas_fused"
+    A = jnp.ones((n1, n2), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda a: sketch_matmul(
+        a, seed=3, r=r, interpret=True, **plan.blocks))(A)
+    (call,) = [e for e in _eqns(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    gm = call.params["grid_mapping"]
+    bk = gm.block_mappings[0].block_shape[1].block_size    # A's (bm, bk)
+    bn = gm.block_mappings[-1].block_shape[1].block_size   # B's (bm, bn)
+    with fresh_metrics() as reg:
+        B = plan.execute(A, seed=3)
+        assert B.shape == (n1, r)
+        gen = reg.counter("omega_entries_generated_total")
+        need = reg.counter("omega_entries_needed_total")
+        assert gen.value(kernel="sketch_a_omega") == \
+            math.prod(gm.grid) * bk * bn == 3 * n2 * r
+        assert need.value(kernel="sketch_a_omega") == n2 * r
+
+
+# kernel -> (its wrapper, operand shape, axis): the operand's block has the
+# contraction bk on ``axis``, the output's block the Omega tile's other side
+_OMEGA_LAUNCHES = {
+    "sketch_a_omega": ("sketch_matmul", (520, 96), 1),
+    "sketch_omega_t_b": ("sketch_t_matmul", (96, 40), 0),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(_OMEGA_LAUNCHES))
+def test_omega_counters_match_each_kernel_grid(kernel):
+    """Each eager launch counts its grid steps times its (bk, block) Omega
+    tile, read off the pallas_call it traces to."""
+    from repro.kernels import ops
+    wrapper, shape, axis = _OMEGA_LAUNCHES[kernel]
+    fn = getattr(ops, wrapper)
+    X = jnp.ones(shape, jnp.float32)
+    r = 16
+    jaxpr = jax.make_jaxpr(lambda x: fn(x, seed=5, r=r, interpret=True))(X)
+    (call,) = [e for e in _eqns(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    gm = call.params["grid_mapping"]
+    out_block = gm.block_mappings[-1].block_shape
+    bk = gm.block_mappings[0].block_shape[axis].block_size
+    tile = bk * out_block[axis].block_size
+    with fresh_metrics() as reg:
+        for calls in (1, 2):
+            fn(X, seed=5, r=r, interpret=True)
+            gen = reg.counter("omega_entries_generated_total")
+            need = reg.counter("omega_entries_needed_total")
+            assert gen.value(kernel=kernel) == calls * math.prod(gm.grid) \
+                * tile
+            assert need.value(kernel=kernel) == calls * shape[axis] * r
+        assert set(gen.snapshot()) == {f'{{kernel="{kernel}"}}'}
+
+
+def test_nystrom_fused_plan_counts_both_kernels():
+    from repro.kernels.ops import sketch_matmul_launch, sketch_t_matmul_launch
+    from repro.plan import plan_nystrom
+    n, r = 96, 16
+    plan = plan_nystrom(n, r, P=1, allow_pallas=True)
+    assert plan.variant == "pallas_fused"
+    with fresh_metrics() as reg:
+        plan.execute(jnp.ones((n, n), jnp.float32), seed=2)
+        gen = reg.counter("omega_entries_generated_total")
+        need = reg.counter("omega_entries_needed_total")
+        a = sketch_matmul_launch(n, n, r, **plan.blocks)
+        t = sketch_t_matmul_launch(n, r, r)
+        assert gen.value(kernel="sketch_a_omega") == a.generated
+        assert gen.value(kernel="sketch_omega_t_b") == t.generated
+        assert need.value(kernel="sketch_a_omega") == n * r
+        assert need.value(kernel="sketch_omega_t_b") == n * r
+
+
+def test_omega_launch_traced_into_a_program_is_not_counted():
+    """A launch inside an enclosing jit runs on every call of that
+    program, which host code does not see: it publishes nothing."""
+    from repro.kernels.ops import sketch_matmul
+    with fresh_metrics() as reg:
+        f = jax.jit(lambda a: 2 * sketch_matmul(a, seed=1, r=8,
+                                                interpret=True))
+        f(jnp.ones((64, 32), jnp.float32))
+        assert "omega_entries_generated_total" not in reg.names()
+        sketch_matmul(jnp.ones((64, 32), jnp.float32), seed=1, r=8,
+                      interpret=True)
+        assert "omega_entries_generated_total" in reg.names()
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            sub = getattr(v, "jaxpr", v)        # a ClosedJaxpr, or a Jaxpr
+            if hasattr(sub, "eqns"):
+                yield from _eqns(sub)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,13 @@
-"""The Pallas kernels compile for a TPU v5e, at the chip smoke's widths.
+"""The Pallas kernels compile for a TPU v5e, at the chip smoke's widths,
+under their names.
 
 Interpret mode on the CPU cannot see what the TPU compiler refuses: VMEM
 over the scoped limit, an unaligned dynamic slice, an unsupported cast.
 These tests compile each kernel of the main path for one chip of a
 described (not attached) ``v5e:2x2`` topology and assert that the compiled
-program calls the Mosaic kernel (``tpu_custom_call``).  Nothing runs.
+program calls the Mosaic kernel (``tpu_custom_call``) and that each
+kernel's call carries its ``pallas_call`` name (the device op's name in
+a profile).  Nothing runs.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
@@ -14,6 +17,7 @@ persistent compilation cache off, since a program compiled for a described
 chip is written to it but cannot be read back without one.
 """
 import os
+import re
 
 import pytest
 
@@ -120,3 +124,45 @@ def test_default_blocks_fit_the_scoped_vmem():
         assert local.vmem_fit_bytes(bm, bn, bk) <= local.VMEM_BUDGET
         sk = local.gen_rows(bk, max(bm, bn))
         assert bk % sk == 0 and sk % 8 == 0
+
+
+# each pallas_call's name -> a call of that kernel alone, at small widths
+_NAMED = {
+    "sketch_a_omega": (lambda a: ops.sketch_matmul(a, seed=0, r=128),
+                       [((1024, 1024), jnp.float32)]),
+    "sketch_omega_t_b": (lambda b: ops.sketch_t_matmul(b, seed=0, r=128),
+                         [((1024, 128), jnp.float32)]),
+    "gen_omega": (lambda: ops.gen_omega(seed=0, n2=512, r=128), []),
+    "sketch_block": (
+        lambda a: local.sketch_block(a, 0, 128, backend="pallas",
+                                     interpret=False),
+        [((1024, 1024), jnp.float32)]),
+    "sketch_t_block": (
+        lambda b: local.sketch_t_block(b, 0, 128, backend="pallas",
+                                       interpret=False),
+        [((1024, 128), jnp.float32)]),
+    "gemm_block": (
+        lambda a, b: local.gemm_block(a, b, backend="pallas",
+                                      interpret=False),
+        [((256, 512), jnp.float32), ((512, 128), jnp.float32)]),
+    "fold_rows_block": (
+        lambda y, d, s: local.fold_rows_block(y, d, s, backend="pallas",
+                                              interpret=False),
+        [((512, 128), jnp.float32), ((64, 128), jnp.float32),
+         ((), jnp.int32)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_NAMED))
+def test_kernel_carries_its_name(one_chip, name):
+    """The compiled program's one custom call is named after the kernel."""
+    fn, args = _NAMED[name]
+    shapes = [_shape(one_chip, s, dt) for s, dt in args]
+    text = jax.jit(fn, out_shardings=one_chip).lower(*shapes).compile() \
+        .as_text()
+    lines = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    named = [ln for ln in lines
+             if re.search(rf"%{name}(\.\d+)? = ", ln)]
+    assert len(lines) == 1 and named == lines, (name, [ln[:80]
+                                                       for ln in lines])
