@@ -10,7 +10,7 @@ executes the kernel body in Python on CPU for validation.
 
 Each product wrapper sets up its launch (blocks, padding, grid) with one
 function, ``sketch_matmul_launch`` / ``sketch_t_matmul_launch``, which
-also counts the Omega entries that grid generates; the wrapper publishes
+also counts the Omega entries that launch generates; the wrapper publishes
 them as ``omega_entries_generated_total{kernel}`` and
 ``omega_entries_needed_total{kernel}`` in the process-wide metrics
 registry, then runs the jitted, padded launch.
@@ -30,6 +30,7 @@ from .sketch_matmul import (
     gen_omega_pallas,
     sketch_matmul_pallas,
     sketch_t_matmul_pallas,
+    uses_panel,
 )
 
 
@@ -43,13 +44,14 @@ class Launch(NamedTuple):
     padded: Tuple[int, int, int]    # the grid's dims padded to the blocks
     generated: int                  # Omega entries the kernel generates
     needed: int                     # distinct Omega entries the product uses
+    panel: bool = False             # sketch_a_omega keeps its Omega panel
 
 
 def _launch(dims, blocks, omega_axis: int, needed: int) -> Launch:
     """Blocks clamped to ``dims`` (rounded up to 8), ``dims`` padded to
-    them; the grid is ``padded / blocks``, the contraction last.  Every
-    grid step of the fused kernels generates its whole Omega tile, (bk,
-    the block of grid axis ``omega_axis``)."""
+    them; the grid has ``padded / blocks`` steps, the contraction last.
+    ``generated`` counts every step's whole Omega tile, (bk, the block of
+    axis ``omega_axis``)."""
     bs = tuple(min(b, _round_up(d, 8)) for d, b in zip(dims, blocks))
     padded = tuple(_round_up(d, b) for d, b in zip(dims, bs))
     steps = math.prod(p // b for p, b in zip(padded, bs))
@@ -59,10 +61,17 @@ def _launch(dims, blocks, omega_axis: int, needed: int) -> Launch:
 @functools.lru_cache(maxsize=None)
 def sketch_matmul_launch(n1: int, n2: int, r: int, bm: int = 256,
                          bn: int = 128, bk: int = 512) -> Launch:
-    """:func:`sketch_matmul` on A n1 x n2: grid ``(n1p/bm, rp/bn, n2p/bk)``,
+    """:func:`sketch_matmul` on A n1 x n2: grid ``(rp/bn, n1p/bm, n2p/bk)``,
     a (bk, bn) tile a step.  The tile depends only on the step's column and
-    contraction blocks, so each row block regenerates all of Omega."""
-    return _launch((n1, r, n2), (bm, bn, bk), 1, n2 * r)
+    contraction blocks: on the panel path
+    (:func:`~repro.kernels.sketch_matmul.uses_panel`) the first row block
+    generates each tile once, ``n2p·rp`` entries; otherwise each row block
+    regenerates all of Omega."""
+    launch = _launch((n1, r, n2), (bm, bn, bk), 1, n2 * r)
+    (bm, bn, _), (n1p, rp, n2p) = launch.blocks, launch.padded
+    if uses_panel(n1p, n2p, bm, bn):
+        return launch._replace(generated=n2p * rp, panel=True)
+    return launch
 
 
 @functools.lru_cache(maxsize=None)

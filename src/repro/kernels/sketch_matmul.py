@@ -14,10 +14,19 @@ name in a profile):
   * ``gen_omega``         — materialize an Omega tile (bitwise oracle
                             check for the in-kernel generator)
 
-Tiling: grid (n1/bm, r/bn, n2/bk) with the contraction dim innermost; an
-f32 VMEM scratch accumulates across k-steps so inputs/outputs may be bf16.
-Block shapes default to MXU-aligned multiples of 128 on TPU; tests sweep
-small blocks in interpret mode.
+Tiling: ``sketch_a_omega``'s grid is (r/bn, n1/bm, n2/bk), column block
+outermost and the contraction innermost; an f32 VMEM scratch accumulates
+across k-steps so inputs/outputs may be bf16.  Its (bk, bn) Omega tile
+depends only on (k, j), so where A has at least two row blocks and the
+column block's whole Omega panel (n2 x bn) fits the VMEM budget, the
+first row block generates each tile into a VMEM panel and every later row
+block reads it back: Omega is generated once per call, not once per row
+block.  Otherwise (one row block, or a contraction too long for the
+panel) each step generates its tile.  Both paths feed the dot the same
+values, so B is bitwise the same.  ``sketch_omega_t_b``'s grid is (r/bm,
+r2/bn, n/bk) and each step generates its tile.  Block shapes default to
+MXU-aligned multiples of 128 on TPU; tests sweep small blocks in
+interpret mode.
 """
 from __future__ import annotations
 
@@ -56,16 +65,53 @@ def _omega_tile_kernel(seed: int, row0, col0, rows: int, cols: int,
 # B = A @ Omega
 # ---------------------------------------------------------------------------
 
-def _sketch_matmul_body(a_ref, o_ref, acc_ref, *, seed: int, bk: int, bn: int,
-                        nsteps_k: int, kind: str, salt: int):
+# Scoped VMEM ``sketch_a_omega`` asks for besides the panel: Mosaic's
+# default scope, in which the per-step kernel has always compiled.  At
+# the default blocks the v5e compiler allocates 1.38 MiB of it (A's and
+# the output's double buffers and the accumulator).
+STEP_VMEM = 16 * 2 ** 20
+# The most scoped VMEM the panel path may ask for (v5e has 128 MiB per
+# core): at bn = 128 a panel of up to 80 MiB, a contraction of 163,840.
+PANEL_VMEM_BUDGET = 96 * 2 ** 20
+
+
+def panel_bytes(n2: int, bn: int) -> int:
+    """VMEM bytes of an f32 (n2, bn) Omega panel, lanes padded to 128."""
+    return n2 * -(-bn // 128) * 128 * 4
+
+
+def uses_panel(n1: int, n2: int, bm: int, bn: int) -> bool:
+    """Whether ``sketch_a_omega`` on a padded (n1, n2) A keeps its Omega
+    panel: at least two row blocks share it, and it fits the budget."""
+    return (n1 // bm >= 2
+            and panel_bytes(n2, bn) + STEP_VMEM <= PANEL_VMEM_BUDGET)
+
+
+def _sketch_matmul_body(a_ref, o_ref, acc_ref, *panel_ref, seed: int,
+                        bk: int, bn: int, nsteps_k: int, kind: str,
+                        salt: int):
+    j = pl.program_id(0)
+    i = pl.program_id(1)
     k = pl.program_id(2)
-    j = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    om = _omega_tile_kernel(seed, k * bk, j * bn, bk, bn, kind, salt)
+    def tile():
+        return _omega_tile_kernel(seed, k * bk, j * bn, bk, bn, kind, salt)
+
+    if panel_ref:
+        (panel_ref,) = panel_ref
+        rows = pl.ds(pl.multiple_of(k * bk, bk), bk)
+
+        @pl.when(i == 0)
+        def _fill():
+            panel_ref[rows, :] = tile()
+
+        om = panel_ref[rows, :]
+    else:
+        om = tile()
     a = a_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot(a, om, precision=F32,
                                 preferred_element_type=jnp.float32)
@@ -79,23 +125,33 @@ def sketch_matmul_pallas(A, seed: int, r: int, *,
                          bm: int = 256, bn: int = 128, bk: int = 512,
                          kind: str = "normal", salt: int = 0,
                          out_dtype=None, interpret: bool = False):
-    """B = A @ Omega with Omega generated in-kernel. Shapes must be multiples
-    of the block sizes (use :func:`repro.kernels.ops.sketch_matmul` for the
+    """B = A @ Omega with Omega generated in-kernel, through the Omega
+    panel where :func:`uses_panel` says so.  Shapes must be multiples of
+    the block sizes (use :func:`repro.kernels.ops.sketch_matmul` for the
     padded general wrapper)."""
     n1, n2 = A.shape
     assert n1 % bm == 0 and n2 % bk == 0 and r % bn == 0, (A.shape, r, (bm, bn, bk))
     out_dtype = out_dtype or A.dtype
     nsteps_k = n2 // bk
-    grid = (n1 // bm, r // bn, nsteps_k)
+    grid = (r // bn, n1 // bm, nsteps_k)
+    scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
+    vmem = STEP_VMEM
+    if uses_panel(n1, n2, bm, bn):
+        scratch.append(pltpu.VMEM((n2, bn), jnp.float32))
+        vmem += panel_bytes(n2, bn)
 
     return pl.pallas_call(
         functools.partial(_sketch_matmul_body, seed=seed, bk=bk, bn=bn,
                           nsteps_k=nsteps_k, kind=kind, salt=salt),
         grid=grid,
-        in_specs=[pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        in_specs=[pl.BlockSpec((bm, bk), lambda j, i, k: (i, k))],
+        out_specs=pl.BlockSpec((bm, bn), lambda j, i, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n1, r), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=scratch,
+        # the row blocks run in order: row block 0 fills the panel
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
         name="sketch_a_omega",
     )(A)

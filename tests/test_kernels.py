@@ -10,6 +10,7 @@ import jax.numpy as jnp
 from repro.kernels import (
     gen_omega, nystrom_fused, sketch_matmul, sketch_t_matmul,
 )
+from repro.kernels.ops import sketch_matmul_launch
 from repro.kernels.ref import (
     omega_ref, sketch_matmul_ref, sketch_t_matmul_ref,
 )
@@ -144,6 +145,72 @@ def test_block_shape_independence():
             for (bm, bn, bk) in [(8, 8, 8), (16, 16, 32), (32, 8, 96), (64, 32, 48)]]
     for o in outs[1:]:
         np.testing.assert_allclose(o, outs[0], rtol=2e-5, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# sketch_a_omega's Omega panel: with two or more row blocks the first row
+# block generates each (k, j) tile into VMEM and the others read it back.
+# One row block alone takes the per-step path (a tile generated each step),
+# so each row block of A, sketched on its own, is the per-step kernel's
+# answer for those rows — the panel path must equal it bit for bit.
+# ---------------------------------------------------------------------------
+
+def _per_row_block(A, bm, **kw):
+    """B computed one row block at a time (each zero-padded to bm rows),
+    every launch on the per-step path."""
+    n1 = A.shape[0]
+    out = []
+    for i0 in range(0, n1, bm):
+        slab = jnp.pad(A[i0:i0 + bm], ((0, bm - min(bm, n1 - i0)), (0, 0)))
+        assert not sketch_matmul_launch(*slab.shape, kw["r"], bm, kw["bn"],
+                                        kw["bk"]).panel
+        out.append(np.asarray(sketch_matmul(slab, bm=bm, **kw, **I)))
+    return np.concatenate(out)[:n1]
+
+
+@pytest.mark.parametrize("salt", [0, 3])
+@pytest.mark.parametrize("kind", ["normal", "uniform", "rademacher"])
+@pytest.mark.parametrize("shape,r,blocks", [
+    ((64, 96), 32, (16, 16, 32)),    # block-aligned, 4 x 2 x 3 grid
+    ((50, 70), 13, (16, 8, 16)),     # padded on n1, n2 and r
+])
+def test_sketch_matmul_panel_bitwise_per_step(kind, salt, shape, r, blocks):
+    bm, bn, bk = blocks
+    A = jax.random.normal(jax.random.key(8), shape)
+    assert sketch_matmul_launch(*shape, r, bm, bn, bk).panel
+    kw = dict(seed=2**40 + 17, r=r, bn=bn, bk=bk, kind=kind, salt=salt)
+    B = np.asarray(sketch_matmul(A, bm=bm, **kw, **I))
+    np.testing.assert_array_equal(B, _per_row_block(A, bm, **kw))
+    np.testing.assert_allclose(
+        B, np.asarray(sketch_matmul_ref(A, kw["seed"], r, kind, salt)),
+        rtol=2e-5, atol=2e-4)
+
+
+@pytest.mark.parametrize("n1,n2,r,bm,bn,bk,panel", [
+    (64, 96, 32, 16, 16, 32, True),        # four row blocks
+    (32, 96, 32, 16, 16, 32, True),        # two
+    (16, 96, 32, 16, 16, 32, False),       # one
+    (12, 96, 32, 16, 16, 32, False),       # one, bm clamped to 16
+    (512, 32768, 256, 256, 128, 512, True),     # the dense32k shape, cut
+    (512, 262144, 256, 256, 128, 512, False),   # a 128 MiB panel
+])
+def test_sketch_matmul_path_follows_the_shape(n1, n2, r, bm, bn, bk, panel):
+    """The launch and the pallas_call agree on the path, picked from the
+    shapes alone: the panel is the kernel's second VMEM scratch, (n2p, bn)
+    f32, and its launch counts each (k, j) tile once."""
+    launch = sketch_matmul_launch(n1, n2, r, bm, bn, bk)
+    (bm_, bn_, bk_), (n1p, rp, n2p) = launch.blocks, launch.padded
+    assert launch.panel == panel
+    assert launch.generated == n2p * rp * (1 if panel else n1p // bm_)
+    A = jax.ShapeDtypeStruct((n1, n2), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda a: sketch_matmul(
+        a, seed=1, r=r, bm=bm, bn=bn, bk=bk, **I))(A)
+    (call,) = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+               if e.primitive.name == "pallas_call"]
+    gm = call.params["grid_mapping"]
+    assert gm.grid == (rp // bn_, n1p // bm_, n2p // bk_)
+    scratch = [a.shape for a in gm.scratch_avals]
+    assert scratch == [(bm_, bn_)] + ([(n2p, bn_)] if panel else [])
 
 
 # ---------------------------------------------------------------------------

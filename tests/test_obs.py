@@ -315,9 +315,10 @@ def test_plan_execute_observes_its_host_time():
 
 
 def test_omega_counters_match_the_kernel_grid():
-    """Generated entries are the fused kernel's grid steps times its
-    Omega tile; needed are the n2·r entries the product uses.  Three row
-    blocks of 256 (n1 = 520, padded to 768) generate Omega three times."""
+    """Generated entries are each distinct (k, j) Omega tile once: three
+    row blocks of 256 (n1 = 520, padded to 768) share the VMEM panel the
+    first one fills, so the launch generates the n2·r entries the
+    product uses."""
     from repro.kernels.ops import sketch_matmul
     from repro.plan import plan_sketch
     n1, n2, r = 520, 96, 16
@@ -331,30 +332,38 @@ def test_omega_counters_match_the_kernel_grid():
     gm = call.params["grid_mapping"]
     bk = gm.block_mappings[0].block_shape[1].block_size    # A's (bm, bk)
     bn = gm.block_mappings[-1].block_shape[1].block_size   # B's (bm, bn)
+    nj, ni, nk = gm.grid
+    assert ni == 3 and gm.scratch_avals[-1].shape == (nk * bk, bn)
     with fresh_metrics() as reg:
         B = plan.execute(A, seed=3)
         assert B.shape == (n1, r)
         gen = reg.counter("omega_entries_generated_total")
         need = reg.counter("omega_entries_needed_total")
         assert gen.value(kernel="sketch_a_omega") == \
-            math.prod(gm.grid) * bk * bn == 3 * n2 * r
+            nj * nk * bk * bn == n2 * r
         assert need.value(kernel="sketch_a_omega") == n2 * r
 
 
-# kernel -> (its wrapper, operand shape, axis): the operand's block has the
-# contraction bk on ``axis``, the output's block the Omega tile's other side
+# case -> (kernel, its wrapper, operand shape, axis): the operand's block
+# has the contraction bk on ``axis``, the output's block the Omega tile's
+# other side.  sketch_a_omega on three row blocks keeps its Omega panel,
+# on one it generates a tile a step.
 _OMEGA_LAUNCHES = {
-    "sketch_a_omega": ("sketch_matmul", (520, 96), 1),
-    "sketch_omega_t_b": ("sketch_t_matmul", (96, 40), 0),
+    "sketch_a_omega": ("sketch_a_omega", "sketch_matmul", (520, 96), 1),
+    "sketch_a_omega-one-row-block": ("sketch_a_omega", "sketch_matmul",
+                                     (200, 96), 1),
+    "sketch_omega_t_b": ("sketch_omega_t_b", "sketch_t_matmul", (96, 40), 0),
 }
 
 
-@pytest.mark.parametrize("kernel", sorted(_OMEGA_LAUNCHES))
-def test_omega_counters_match_each_kernel_grid(kernel):
-    """Each eager launch counts its grid steps times its (bk, block) Omega
-    tile, read off the pallas_call it traces to."""
+@pytest.mark.parametrize("case", sorted(_OMEGA_LAUNCHES))
+def test_omega_counters_match_each_kernel_grid(case):
+    """Each eager launch counts the Omega tiles its pallas_call generates,
+    read off that call: grid steps times the (bk, block) tile, or, where
+    the kernel keeps a panel (a second scratch), the steps of one row
+    block."""
     from repro.kernels import ops
-    wrapper, shape, axis = _OMEGA_LAUNCHES[kernel]
+    kernel, wrapper, shape, axis = _OMEGA_LAUNCHES[case]
     fn = getattr(ops, wrapper)
     X = jnp.ones(shape, jnp.float32)
     r = 16
@@ -365,13 +374,16 @@ def test_omega_counters_match_each_kernel_grid(kernel):
     out_block = gm.block_mappings[-1].block_shape
     bk = gm.block_mappings[0].block_shape[axis].block_size
     tile = bk * out_block[axis].block_size
+    steps = math.prod(gm.grid)
+    if gm.num_scratch_operands == 2:                # the Omega panel
+        steps //= gm.grid[1]
+    assert (gm.num_scratch_operands == 2) == (case == "sketch_a_omega")
     with fresh_metrics() as reg:
         for calls in (1, 2):
             fn(X, seed=5, r=r, interpret=True)
             gen = reg.counter("omega_entries_generated_total")
             need = reg.counter("omega_entries_needed_total")
-            assert gen.value(kernel=kernel) == calls * math.prod(gm.grid) \
-                * tile
+            assert gen.value(kernel=kernel) == calls * steps * tile
             assert need.value(kernel=kernel) == calls * shape[axis] * r
         assert set(gen.snapshot()) == {f'{{kernel="{kernel}"}}'}
 

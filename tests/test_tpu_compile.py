@@ -25,6 +25,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import local, ops
+from repro.kernels.sketch_matmul import (
+    PANEL_VMEM_BUDGET, STEP_VMEM, panel_bytes,
+)
 
 # the chip smoke's per-chip widths (chip_smoke.py)
 N, R = 32768, 256                  # dense sketch / Nyström: A is N x N
@@ -124,6 +127,32 @@ def test_default_blocks_fit_the_scoped_vmem():
         assert local.vmem_fit_bytes(bm, bn, bk) <= local.VMEM_BUDGET
         sk = local.gen_rows(bk, max(bm, bn))
         assert bk % sk == 0 and sk % 8 == 0
+
+
+# sketch_a_omega at r = R: case -> (rows of A, its contraction, whether the
+# kernel keeps its (n2, 128) f32 Omega panel in VMEM)
+_OMEGA_PANEL = {
+    "dense32k": (N, N, True),                  # a 16 MiB panel
+    "dense56k": (57344, 57344, True),          # a 28 MiB panel
+    "long-contraction": (512, 262144, False),  # 128 MiB: over the budget
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OMEGA_PANEL))
+def test_sketch_a_omega_compiles_on_its_path(one_chip, case):
+    """The launch picks the panel path from the shape, the kernel
+    compiles under the scoped VMEM it asks for, and that is the step's
+    share plus the panel, within the budget."""
+    n1, n2, panel = _OMEGA_PANEL[case]
+    assert ops.sketch_matmul_launch(n1, n2, R).panel == panel
+    text = jax.jit(lambda a: ops.sketch_matmul(a, seed=0, r=R)).lower(
+        _shape(one_chip, (n1, n2))).compile().as_text()
+    (line,) = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    asked = int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                          line).group(1))
+    want = STEP_VMEM + (panel_bytes(n2, 128) if panel else 0)
+    assert asked == want <= PANEL_VMEM_BUDGET, (asked, want)
 
 
 # each pallas_call's name -> a call of that kernel alone, at small widths
