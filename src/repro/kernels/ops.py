@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.obs.metrics import get_metrics
+from repro.obs.trace import span
 
 from .sketch_matmul import (
     gen_omega_pallas,
@@ -171,9 +172,17 @@ def gen_omega(*, seed: int, n2: int, r: int, br: int = 256, bc: int = 128,
 def nystrom_fused(A, *, seed: int, r: int, kind: str = "normal",
                   interpret: bool = False, **blocks):
     """(B, C) of the Nyström pair with Omega never materialized in HBM:
-    B = A·Omega via the fused kernel, then C = Omega^T·B likewise."""
-    B = sketch_matmul(A, seed=seed, r=r, kind=kind, interpret=interpret,
-                      **{k: v for k, v in blocks.items()
-                         if k in ("bm", "bn", "bk")})
-    C = sketch_t_matmul(B, seed=seed, r=r, kind=kind, interpret=interpret)
+    B = A·Omega via the fused kernel, then C = Omega^T·B likewise.  Each
+    launch's dispatch is a span, ``nystrom.stage1`` / ``nystrom.stage2``,
+    with its shapes and blocks."""
+    n = A.shape[0]
+    blocks = {k: v for k, v in blocks.items() if k in ("bm", "bn", "bk")}
+    with span("nystrom.stage1", cat="nystrom", n=n, r=r,
+              blocks=list(sketch_matmul_launch(*A.shape, r, **blocks)
+                          .blocks)):
+        B = sketch_matmul(A, seed=seed, r=r, kind=kind, interpret=interpret,
+                          **blocks)
+    with span("nystrom.stage2", cat="nystrom", n=n, r=r,
+              blocks=list(sketch_t_matmul_launch(n, r, r).blocks)):
+        C = sketch_t_matmul(B, seed=seed, r=r, kind=kind, interpret=interpret)
     return B, C

@@ -406,6 +406,34 @@ def test_nystrom_fused_plan_counts_both_kernels():
         assert need.value(kernel="sketch_omega_t_b") == n * r
 
 
+def test_nystrom_fused_stages_are_spans_under_plan_execute(tmp_path):
+    """Each stage's dispatch is a span inside ``plan.execute``, in the
+    tracer and in a profile, with its shapes and blocks."""
+    from repro.kernels.ops import sketch_matmul_launch, sketch_t_matmul_launch
+    from repro.plan import plan_nystrom
+    n, r = 96, 16
+    plan = plan_nystrom(n, r, P=1, allow_pallas=True)
+    assert plan.variant == "pallas_fused"
+    A = jnp.ones((n, n), jnp.float32)
+    t = obs.install_tracer()
+    events = _profiled(tmp_path, lambda: jax.block_until_ready(
+        plan.execute(A, seed=2)))
+    spans = {s.name: s for s in t.spans}
+    launches = {"nystrom.stage1": sketch_matmul_launch(n, n, r,
+                                                       **plan.blocks),
+                "nystrom.stage2": sketch_t_matmul_launch(n, r, r)}
+    (outer,) = [e for e in events if e[1] == "plan.execute"]
+    stages = []
+    for name, launch in launches.items():
+        assert spans[name].parent_id == spans["plan.execute"].span_id
+        assert spans[name].args == {"n": n, "r": r,
+                                    "blocks": list(launch.blocks)}
+        (event,) = [e for e in events if e[1] == name]
+        assert event[0] == outer[0] and _inside(event, outer)
+        stages.append(event)
+    assert stages[0][3] <= stages[1][2]
+
+
 def test_omega_launch_traced_into_a_program_is_not_counted():
     """A launch inside an enclosing jit runs on every call of that
     program, which host code does not see: it publishes nothing."""

@@ -139,6 +139,18 @@ def test_alg2_choice_equals_closed_form():
             4096, 256, plan.grid, plan.q_grid)
 
 
+def test_one_chip_nystrom_plans_the_fused_kernels():
+    """On one v5e the chip-filling pair (A 13.15 GB) plans the fused
+    variant with the default blocks: it reads no Omega words."""
+    from repro.plan.planner import DEFAULT_BLOCKS
+    plan = plan_nystrom(57344, 256, P=1, machine=PRESETS["tpu_v5e"])
+    assert (plan.variant, plan.backend) == ("pallas_fused", "pallas")
+    assert plan.blocks == DEFAULT_BLOCKS and plan.executable
+    local = [c for c in plan.candidates if c.variant == "local_xla"]
+    assert local[0].cost.hbm_words - plan.predicted_hbm_words == \
+        2 * 57344 * 256
+
+
 def test_zero_communication_regime_below_crossover():
     """(c): P <= n1 -> the (P, 1, 1) local-regenerate grid, zero words."""
     for P in (2, 8, 32, 64):

@@ -155,6 +155,27 @@ def test_sketch_a_omega_compiles_on_its_path(one_chip, case):
     assert asked == want <= PANEL_VMEM_BUDGET, (asked, want)
 
 
+def test_nystrom_fused_compiles_at_dense56k(one_chip):
+    """The chip-filling pair (A 57344^2 f32, 82% of HBM): stage 1 on its
+    panel path, stage 2 generating each Omega tile for both column blocks
+    of B, and no copy of A: temporaries far below A's size."""
+    n = 57344
+    assert ops.sketch_matmul_launch(n, n, R).panel
+    assert ops.sketch_t_matmul_launch(n, R, R).generated == 2 * n * R
+    compiled = jax.jit(lambda a: ops.nystrom_fused(
+        a, seed=20260316, r=R, bm=256, bn=128, bk=512)).lower(
+            _shape(one_chip, (n, n))).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 4 * n * n
+    assert mem.output_size_in_bytes == pytest.approx(4 * (n * R + R * R),
+                                                     rel=1e-4)   # + tuple
+    assert mem.temp_size_in_bytes < 2 ** 20
+    calls = [re.search(r"%(\w+)", ln).group(1)
+             for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls == ["sketch_a_omega", "sketch_omega_t_b"], calls
+
+
 # each pallas_call's name -> a call of that kernel alone, at small widths
 _NAMED = {
     "sketch_a_omega": (lambda a: ops.sketch_matmul(a, seed=0, r=128),
