@@ -31,6 +31,7 @@ from .sketch_matmul import (
     gen_omega_pallas,
     sketch_matmul_pallas,
     sketch_t_matmul_pallas,
+    t_block_n,
     uses_panel,
 )
 
@@ -77,9 +78,13 @@ def sketch_matmul_launch(n1: int, n2: int, r: int, bm: int = 256,
 
 @functools.lru_cache(maxsize=None)
 def sketch_t_matmul_launch(n: int, r2: int, r: int, bm: int = 128,
-                           bn: int = 128, bk: int = 512) -> Launch:
+                           bn: int | None = None, bk: int = 512) -> Launch:
     """:func:`sketch_t_matmul` on B n x r2: grid ``(rp/bm, r2p/bn, np/bk)``,
-    a (bk, bm) tile a step, regenerated for each column block of B."""
+    a (bk, bm) tile a step, generated once per column block of B.  Unless
+    the caller gives ``bn``, one column block covers B where the step fits
+    (:func:`~repro.kernels.sketch_matmul.t_block_n`): ``np·rp`` entries."""
+    if bn is None:
+        bn = t_block_n(r2, bm, bk)
     return _launch((r, r2, n), (bm, bn, bk), 0, n * r)
 
 
@@ -126,7 +131,7 @@ def _sketch_matmul(A, *, seed, r, blocks, padded, kind, salt, interpret):
 
 
 def sketch_t_matmul(B, *, seed: int, r: int,
-                    bm: int = 128, bn: int = 128, bk: int = 512,
+                    bm: int = 128, bn: int | None = None, bk: int = 512,
                     kind: str = "normal", salt: int = 0,
                     interpret: bool = False):
     """C = Omega(n, r)^T @ B with in-kernel Omega generation; any shape.

@@ -24,9 +24,11 @@ block reads it back: Omega is generated once per call, not once per row
 block.  Otherwise (one row block, or a contraction too long for the
 panel) each step generates its tile.  Both paths feed the dot the same
 values, so B is bitwise the same.  ``sketch_omega_t_b``'s grid is (r/bm,
-r2/bn, n/bk) and each step generates its tile.  Block shapes default to
-MXU-aligned multiples of 128 on TPU; tests sweep small blocks in
-interpret mode.
+r2/bn, n/bk) and each step generates its (bk, bm) tile, which depends
+only on (k, i): where the step fits, one column block covers all of B
+(:func:`t_block_n`), so each tile is generated once per call.  Block
+shapes default to MXU-aligned multiples of 128 on TPU; tests sweep small
+blocks in interpret mode.
 """
 from __future__ import annotations
 
@@ -160,6 +162,31 @@ def sketch_matmul_pallas(A, seed: int, r: int, *,
 # ---------------------------------------------------------------------------
 # C = Omega^T @ B    (contraction over Omega rows: the Nystrom second stage)
 # ---------------------------------------------------------------------------
+
+# The most VMEM a ``sketch_omega_t_b`` step's own buffers may take: half
+# the default scope, the rest left to Mosaic's (the Omega tile, its
+# transpose, the dot's operands).  At bn = 256 the v5e compiler allocates
+# 2.66 MiB in all; at bn = 1408, the widest that fits, 8.85 MiB.
+T_STEP_BUDGET = STEP_VMEM // 2
+
+
+def t_step_bytes(bm: int, bn: int, bk: int) -> int:
+    """VMEM bytes of one ``sketch_omega_t_b`` step's f32 buffers: B's
+    double-buffered (bk, bn) block, the output's double-buffered (bm, bn)
+    block and the (bm, bn) accumulator."""
+    return 4 * bn * (2 * bk + 3 * bm)
+
+
+def t_block_n(r2: int, bm: int, bk: int) -> int:
+    """Column block of B for ``sketch_omega_t_b``: B's whole width, in
+    lanes of 128, where the step fits :data:`T_STEP_BUDGET`, so each Omega
+    tile is generated once.  Otherwise the fewest column blocks that fit,
+    as even as lanes of 128 allow; each tile is generated once per block."""
+    lanes = -(-r2 // 128)
+    fit = max(T_STEP_BUDGET // t_step_bytes(bm, 128, bk), 1)
+    blocks = -(-lanes // fit)
+    return -(-lanes // blocks) * 128
+
 
 def _sketch_t_matmul_body(b_ref, o_ref, acc_ref, *, seed: int, bk: int,
                           bm: int, nsteps_k: int, kind: str, salt: int):

@@ -10,7 +10,8 @@ import jax.numpy as jnp
 from repro.kernels import (
     gen_omega, nystrom_fused, sketch_matmul, sketch_t_matmul,
 )
-from repro.kernels.ops import sketch_matmul_launch
+from repro.kernels.ops import sketch_matmul_launch, sketch_t_matmul_launch
+from repro.kernels.sketch_matmul import T_STEP_BUDGET, t_step_bytes
 from repro.kernels.ref import (
     omega_ref, sketch_matmul_ref, sketch_t_matmul_ref,
 )
@@ -225,6 +226,52 @@ def test_sketch_t_matmul_vs_ref(shape, r):
     assert C.shape == (r, shape[1])
     np.testing.assert_allclose(np.asarray(C), np.asarray(ref),
                                rtol=3e-5, atol=3e-4)
+
+
+@pytest.mark.parametrize("kind", ["normal", "uniform", "rademacher"])
+@pytest.mark.parametrize("salt", [0, 3])
+@pytest.mark.parametrize("shape,r,bn", [
+    ((128, 48), 16, 16),       # aligned: three column blocks of 16
+    ((100, 70), 13, 24),       # padded in n, r and r2: 70 -> 72
+])
+def test_sketch_t_matmul_one_column_block_is_bitwise_the_split(kind, salt,
+                                                               shape, r, bn):
+    """The default launch covers B with one column block, generating each
+    Omega tile once; C is bitwise the C of a narrow ``bn`` that splits B
+    into several column blocks and regenerates each tile for each."""
+    B = jax.random.normal(jax.random.key(8), shape)
+    kw = dict(seed=2**40 + 5, r=r, kind=kind, salt=salt, **I)
+    launch = sketch_t_matmul_launch(*shape, r)
+    assert launch.padded[1] == launch.blocks[1]
+    assert sketch_t_matmul_launch(*shape, r, bn=bn).padded[1] // bn > 1
+    C = np.asarray(sketch_t_matmul(B, **kw))
+    np.testing.assert_array_equal(C, np.asarray(sketch_t_matmul(B, bn=bn,
+                                                                **kw)))
+    np.testing.assert_allclose(
+        C, np.asarray(sketch_t_matmul_ref(B, kw["seed"], r, kind, salt)),
+        rtol=3e-5, atol=3e-4)
+
+
+@pytest.mark.parametrize("n,r2,r", [
+    (57344, 256, 256),         # the dense56k pair's stage 2
+    (96, 200, 16),             # small: blocks clamped to the shape
+    (57344, 8192, 256),        # a step over the budget at full width
+])
+def test_sketch_t_matmul_launch_picks_the_widest_fitting_block(n, r2, r):
+    """One column block covers B where the step's buffers fit the budget,
+    so the launch generates np·rp entries; a wider B gets the widest
+    multiple of 128 that fits and regenerates each tile once per block."""
+    launch = sketch_t_matmul_launch(n, r2, r)
+    (bm, bn, bk), (rp, r2p, np_) = launch.blocks, launch.padded
+    blocks = -(-r2p // bn)
+    if t_step_bytes(128, -(-r2 // 128) * 128, 512) <= T_STEP_BUDGET:
+        assert bn == r2p and launch.generated == np_ * rp
+    else:
+        assert bn % 128 == 0 and blocks > 1
+        assert t_step_bytes(bm, bn, bk) <= T_STEP_BUDGET \
+            < t_step_bytes(bm, bn + 128, bk)
+        assert launch.generated == blocks * np_ * rp
+    assert launch.needed == n * r
 
 
 def test_nystrom_fused_pair_matches_core():

@@ -347,12 +347,15 @@ def test_omega_counters_match_the_kernel_grid():
 # case -> (kernel, its wrapper, operand shape, axis): the operand's block
 # has the contraction bk on ``axis``, the output's block the Omega tile's
 # other side.  sketch_a_omega on three row blocks keeps its Omega panel,
-# on one it generates a tile a step.
+# on one it generates a tile a step.  sketch_omega_t_b covers B with one
+# column block, also where B is wider than 128 columns.
 _OMEGA_LAUNCHES = {
     "sketch_a_omega": ("sketch_a_omega", "sketch_matmul", (520, 96), 1),
     "sketch_a_omega-one-row-block": ("sketch_a_omega", "sketch_matmul",
                                      (200, 96), 1),
     "sketch_omega_t_b": ("sketch_omega_t_b", "sketch_t_matmul", (96, 40), 0),
+    "sketch_omega_t_b-wide": ("sketch_omega_t_b", "sketch_t_matmul",
+                              (96, 200), 0),
 }
 
 
@@ -378,6 +381,8 @@ def test_omega_counters_match_each_kernel_grid(case):
     if gm.num_scratch_operands == 2:                # the Omega panel
         steps //= gm.grid[1]
     assert (gm.num_scratch_operands == 2) == (case == "sketch_a_omega")
+    if kernel == "sketch_omega_t_b":
+        assert gm.grid[1] == 1                      # one column block of B
     with fresh_metrics() as reg:
         for calls in (1, 2):
             fn(X, seed=5, r=r, interpret=True)

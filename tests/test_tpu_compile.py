@@ -157,11 +157,12 @@ def test_sketch_a_omega_compiles_on_its_path(one_chip, case):
 
 def test_nystrom_fused_compiles_at_dense56k(one_chip):
     """The chip-filling pair (A 57344^2 f32, 82% of HBM): stage 1 on its
-    panel path, stage 2 generating each Omega tile for both column blocks
-    of B, and no copy of A: temporaries far below A's size."""
+    panel path, stage 2 covering B with one column block, so generating
+    each Omega tile once, within the default VMEM scope, and no copy of
+    A: temporaries far below A's size."""
     n = 57344
     assert ops.sketch_matmul_launch(n, n, R).panel
-    assert ops.sketch_t_matmul_launch(n, R, R).generated == 2 * n * R
+    assert ops.sketch_t_matmul_launch(n, R, R).generated == n * R
     compiled = jax.jit(lambda a: ops.nystrom_fused(
         a, seed=20260316, r=R, bm=256, bn=128, bk=512)).lower(
             _shape(one_chip, (n, n))).compile()
@@ -170,10 +171,21 @@ def test_nystrom_fused_compiles_at_dense56k(one_chip):
     assert mem.output_size_in_bytes == pytest.approx(4 * (n * R + R * R),
                                                      rel=1e-4)   # + tuple
     assert mem.temp_size_in_bytes < 2 ** 20
-    calls = [re.search(r"%(\w+)", ln).group(1)
-             for ln in compiled.as_text().splitlines()
+    lines = [ln for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
+    calls = [re.search(r"%(\w+)", ln).group(1) for ln in lines]
     assert calls == ["sketch_a_omega", "sketch_omega_t_b"], calls
+    # stage 2, B's (512, 256) block at full width, asks for no more than
+    # the default scope, and its compiled step uses less than that
+    stage2 = lines[1]
+    asked = re.search(r'"scoped_memory_configs":\[([^\]]*)\]',
+                      stage2).group(1)
+    assert all(int(s) <= STEP_VMEM
+               for s in re.findall(r'"size":"(\d+)"', asked)), asked
+    used = int(re.search(
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+        stage2).group(1))
+    assert used <= STEP_VMEM, used
 
 
 # each pallas_call's name -> a call of that kernel alone, at small widths
