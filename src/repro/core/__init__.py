@@ -17,9 +17,10 @@ from .sketch import (  # noqa: F401
     DENSE_KINDS, SPARSE_KINDS, VALID_KINDS,
     rand_matmul, rand_matmul_auto, rand_matmul_communicating,
     sketch_reference, sketch_sparse_apply, sparse_omega_map,
-    sparse_omega_rows, omega_tile, seed_keys, make_grid_mesh,
+    sparse_omega_rows, omega_tile, make_grid_mesh,
     validate_kind,
 )
+from .rng import seed_keys  # noqa: F401
 from .nystrom import (  # noqa: F401
     nystrom_reference, nystrom_no_redist, nystrom_redist, nystrom_general,
     nystrom_two_grid, nystrom_two_grid_fused, nystrom_auto,

@@ -56,7 +56,8 @@ from repro.obs import trace as obs_trace
 from .compat import shard_map
 from .sketch import (DEFAULT_AXES, F32, _PROG_CACHE_SIZE, SPARSE_KINDS,
                      input_sharding, make_grid_mesh, omega_tile, rand_matmul,
-                     seed_keys, validate_kind)
+                     validate_kind)
+from .rng import seed_keys
 
 X_AXIS = "x"
 

@@ -3,27 +3,22 @@
 The paper's central systems insight (§6.3) is that a dense random sketching
 matrix Omega never needs to be *communicated*: any processor can regenerate
 exactly the block it consumes from a shared seed using a counter-based PRNG
-(they use Philox-4x32-10 via MKL/cuRAND).  This module provides two
-realizations of that insight:
+(they use Philox-4x32-10 via MKL/cuRAND).  This module is the one Omega
+generator: a pure-jnp Philox-4x32-10 (the paper's exact generator, Salmon
+et al. SC'11), written only with uint32 ops and 16-bit-limb multiplies so
+the identical bitstream is reproducible inside a Pallas TPU kernel (no
+64-bit multiply on the TPU VPU).  Entries are keyed by *global*
+coordinates, so any tiling or processor grid regenerates bit-identical
+blocks.
 
-1. ``block_omega`` / ``omega_full`` — JAX-native. JAX's threefry PRNG is
-   itself counter-based, so ``fold_in(key, linear_block_index)`` gives a
-   deterministic, device-local, communication-free block of Omega.  The block
-   grid is defined *globally* (independent of the mesh), so any processor
-   grid regenerates bit-identical entries — this is what makes the
-   distributed algorithms bitwise-equal to the single-device reference.
-
-2. ``philox_4x32`` / ``philox_uniform`` / ``philox_normal`` — a pure-jnp
-   Philox-4x32-10 (the paper's exact generator, Salmon et al. SC'11),
-   written only with uint32 ops and 16-bit-limb multiplies so the identical
-   bitstream is reproducible inside a Pallas TPU kernel (no 64-bit multiply
-   on the TPU VPU).  ``kernels/local.py`` and ``kernels/sketch_matmul.py``
-   consume these helpers to generate Omega tiles in VMEM, and
-   ``kernels/ref.py`` uses them as the bitwise oracle.
+``seed_keys`` turns a seed into the Philox key pair and ``omega_grid``
+maps (key pair, global offset, tile shape, kind, salt) to a dense f32
+tile.  ``core/sketch.py``'s ``omega_tile``, the kernel bodies of
+``kernels/sketch_matmul.py`` and ``kernels/local.py``, and the oracle
+``kernels/ref.py`` all generate dense Omega through ``omega_grid``.
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
@@ -187,6 +182,56 @@ def philox_normal_grid(key0: jnp.ndarray, key1: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# The dense Omega generator: seed -> key pair, (kind, offset, shape) -> tile
+# ---------------------------------------------------------------------------
+
+def seed_keys(seed):
+    """The Philox (key0, key1) pair for a seed.
+
+    ``seed`` may be a Python int (split into two uint32 halves, as the
+    one-shot APIs have always done) or a JAX value — a scalar or a shape-(2,)
+    uint32 array — so the streaming sketch service can trace the seed and
+    share one compiled update executable across every concurrent stream.
+    A Python int < 2**32 and the equivalent traced uint32 scalar produce
+    bitwise-identical Omega entries.
+    """
+    if isinstance(seed, (int, np.integer)):
+        seed = int(seed)
+        return (jnp.uint32(seed & 0xFFFFFFFF),
+                jnp.uint32((seed >> 32) & 0xFFFFFFFF))
+    seed = jnp.asarray(seed)
+    if seed.shape == (2,):
+        return seed[0].astype(jnp.uint32), seed[1].astype(jnp.uint32)
+    if seed.shape == ():
+        return seed.astype(jnp.uint32), jnp.zeros((), jnp.uint32)
+    raise ValueError(f"seed must be an int, a scalar, or a (2,) key pair; "
+                     f"got shape {seed.shape}")
+
+
+def omega_grid(keys, row0, col0, rows: int, cols: int,
+               kind: str = "normal", salt: int = 0) -> jnp.ndarray:
+    """The (rows, cols) f32 tile at global offset (row0, col0) of a dense
+    Omega: ``normal`` (Irwin-Hall), ``uniform`` on [0, 1), or
+    ``rademacher`` (+-1, the uniform grid's ``u < 0.5``).
+
+    ``keys`` is a (key0, key1) uint32 pair (:func:`seed_keys`); the
+    offsets may be traced.  Only uint32 and exact ops, so the jnp paths
+    and the Pallas kernel bodies get the same bits from one definition.
+    """
+    key0, key1 = keys
+    row0 = jnp.asarray(row0, jnp.uint32)
+    col0 = jnp.asarray(col0, jnp.uint32)
+    if kind == "normal":
+        return philox_normal_grid(key0, key1, row0, col0, rows, cols, salt)
+    if kind == "uniform":
+        return philox_uniform_grid(key0, key1, row0, col0, rows, cols, salt)
+    if kind == "rademacher":
+        u = philox_uniform_grid(key0, key1, row0, col0, rows, cols, salt)
+        return jnp.where(u < 0.5, jnp.float32(-1), jnp.float32(1))
+    raise ValueError(f"unknown omega kind {kind!r}")
+
+
+# ---------------------------------------------------------------------------
 # Sparse sketch family draws (CountSketch buckets/signs + coordinated
 # sampling membership).  Same determinism contract as the grids above:
 # pure uint32 Philox on GLOBAL coordinates, zero roundable float ops, so
@@ -278,62 +323,3 @@ def philox_rowsample_grid(key0: jnp.ndarray, key1: jnp.ndarray,
     val = jnp.where(u < np.float32(p), sign * scale, jnp.float32(0.0))
     gj = _u32(col0) + jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 1)
     return jnp.where(bucket[:, None] == gj, val[:, None], jnp.float32(0.0))
-
-
-# ---------------------------------------------------------------------------
-# JAX-threefry block Omega (used by the distributed shard_map algorithms)
-# ---------------------------------------------------------------------------
-
-def _as_key(seed_or_key):
-    if isinstance(seed_or_key, (int, np.integer)):
-        return jax.random.key(seed_or_key)
-    return seed_or_key
-
-
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def block_omega(key, j, k, block_rows: int, block_cols: int,
-                n_block_cols: int, dtype=jnp.float32, kind: str = "normal"):
-    """Block (j, k) of the global random matrix Omega.
-
-    The (j, k) indexing is over a *global* block grid of
-    ``block_rows x block_cols`` tiles covering Omega (n2 x r).  Any processor
-    calls this with its own (j, k) — zero communication, deterministic in
-    ``key``.  Different (mesh, grid) decompositions must use the *same*
-    (block_rows, block_cols) to be bitwise-consistent; `omega_full`
-    reassembles the same matrix on one device.
-    """
-    key = _as_key(key)
-    kk = jax.random.fold_in(key, j * n_block_cols + k)
-    if kind == "normal":
-        return jax.random.normal(kk, (block_rows, block_cols), dtype)
-    elif kind == "uniform":
-        return jax.random.uniform(kk, (block_rows, block_cols), dtype)
-    elif kind == "rademacher":
-        return jax.random.rademacher(kk, (block_rows, block_cols), dtype)
-    raise ValueError(f"unknown omega kind: {kind}")
-
-
-def omega_full(key, n2: int, r: int, p2: int, p3: int,
-               dtype=jnp.float32, kind: str = "normal"):
-    """Assemble the full Omega from its (p2 x p3) block grid on one device.
-
-    Reference/oracle path: must equal the concatenation of every processor's
-    ``block_omega`` outputs.
-    """
-    assert n2 % p2 == 0 and r % p3 == 0, (n2, r, p2, p3)
-    br, bc = n2 // p2, r // p3
-    rows = []
-    for j in range(p2):
-        cols = [block_omega(key, j, k, br, bc, p3, dtype, kind)
-                for k in range(p3)]
-        rows.append(jnp.concatenate(cols, axis=1))
-    return jnp.concatenate(rows, axis=0)
-
-
-def philox_omega_full(seed: int, n2: int, r: int, dtype=jnp.float32,
-                      salt: int = 0):
-    """Full Omega from the Philox path (tile-decomposition independent)."""
-    key0 = _u32(seed & 0xFFFFFFFF)
-    key1 = _u32((seed >> 32) & 0xFFFFFFFF)
-    return philox_normal_grid(key0, key1, _u32(0), _u32(0), n2, r,
-                              salt=salt).astype(dtype)

@@ -52,29 +52,6 @@ from .kinds import (DENSE_KINDS, SPARSE_KINDS,  # noqa: F401,E402
 # Omega tile generation (shared by local + distributed paths)
 # ---------------------------------------------------------------------------
 
-def seed_keys(seed):
-    """The Philox (key0, key1) pair for a seed.
-
-    ``seed`` may be a Python int (split into two uint32 halves, as the
-    one-shot APIs have always done) or a JAX value — a scalar or a shape-(2,)
-    uint32 array — so the streaming sketch service can trace the seed and
-    share one compiled update executable across every concurrent stream.
-    A Python int < 2**32 and the equivalent traced uint32 scalar produce
-    bitwise-identical Omega entries.
-    """
-    if isinstance(seed, (int, np.integer)):
-        seed = int(seed)
-        return (jnp.uint32(seed & 0xFFFFFFFF),
-                jnp.uint32((seed >> 32) & 0xFFFFFFFF))
-    seed = jnp.asarray(seed)
-    if seed.shape == (2,):
-        return seed[0].astype(jnp.uint32), seed[1].astype(jnp.uint32)
-    if seed.shape == ():
-        return seed.astype(jnp.uint32), jnp.zeros((), jnp.uint32)
-    raise ValueError(f"seed must be an int, a scalar, or a (2,) key pair; "
-                     f"got shape {seed.shape}")
-
-
 def omega_tile(seed, row0, col0, rows: int, cols: int,
                kind: str = "normal", dtype=jnp.float32, salt: int = 0,
                r_total: Optional[int] = None,
@@ -83,7 +60,7 @@ def omega_tile(seed, row0, col0, rows: int, cols: int,
 
     Entry values depend only on global coordinates + seed, never on the
     tiling, so this is safe to call from any shard with traced offsets.
-    ``seed`` may be traced (see :func:`seed_keys`).
+    ``seed`` may be traced (see :func:`repro.core.rng.seed_keys`).
 
     The sparse kinds need the GLOBAL Omega shape, which a tile call does
     not otherwise carry: ``r_total`` is the global column count (the
@@ -95,17 +72,12 @@ def omega_tile(seed, row0, col0, rows: int, cols: int,
     ignore both.
     """
     validate_kind(kind)
-    key0, key1 = seed_keys(seed)
-    row0 = jnp.asarray(row0, jnp.uint32)
-    col0 = jnp.asarray(col0, jnp.uint32)
-    if kind == "normal":
-        t = rng.philox_normal_grid(key0, key1, row0, col0, rows, cols, salt)
-    elif kind == "uniform":
-        t = rng.philox_uniform_grid(key0, key1, row0, col0, rows, cols, salt)
-    elif kind == "rademacher":
-        u = rng.philox_uniform_grid(key0, key1, row0, col0, rows, cols, salt)
-        t = jnp.where(u < 0.5, -1.0, 1.0)
-    elif kind == "countsketch":
+    keys = rng.seed_keys(seed)
+    if kind in DENSE_KINDS:
+        return rng.omega_grid(keys, row0, col0, rows, cols, kind,
+                              salt).astype(dtype)
+    key0, key1 = keys
+    if kind == "countsketch":
         t = rng.philox_countsketch_grid(key0, key1, row0, col0, rows, cols,
                                         r_total if r_total is not None
                                         else cols, salt)
@@ -155,7 +127,7 @@ def sparse_omega_rows(seed, g, width: int, kind: str, dtype=jnp.float32,
     if kind not in SPARSE_KINDS:
         raise ValueError(f"kind {kind!r} is dense; sparse_omega_rows serves "
                          f"{', '.join(SPARSE_KINDS)}")
-    key0, key1 = seed_keys(seed)
+    key0, key1 = rng.seed_keys(seed)
     g = jnp.asarray(g, jnp.uint32)
     bucket, sign = rng.philox_countsketch_rows(key0, key1, g, width, salt)
     if kind == "countsketch":
@@ -280,7 +252,7 @@ def rand_matmul(A, seed, r: int, mesh: Mesh,
     if n1 % (p1 * p2) or n2 % (p2 * p3) or n2 % p2 or r % p3:
         raise ValueError(f"shape ({n1},{n2},r={r}) not divisible by grid "
                          f"({p1},{p2},{p3})")
-    keys = jnp.stack(seed_keys(seed))
+    keys = jnp.stack(rng.seed_keys(seed))
     fn = _rand_matmul_prog(r, mesh, tuple(axes), kind,
                            None if scale is None else float(scale),
                            precision, salt, resolve_backend(backend),
@@ -422,9 +394,10 @@ def rand_matmul_communicating(A, seed, r: int, mesh: Mesh,
 
     Omega starts distributed over the full mesh (one copy in the system) and
     is all-gathered by every processor before the local GEMM.  Same result,
-    strictly more communication; used by benchmarks/bench_comm_vs_gen.py.
+    strictly more communication; the tests compare its collective bytes
+    with Alg. 1's (``tests/test_sketch_distributed.py``).
     """
-    keys = jnp.stack(seed_keys(seed))
+    keys = jnp.stack(rng.seed_keys(seed))
     return _rand_matmul_communicating_prog(r, mesh, tuple(axes), kind)(A, keys)
 
 

@@ -60,7 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import rng
-from repro.core.sketch import F32, omega_tile, seed_keys
+from repro.core.sketch import F32, omega_tile
 
 BACKENDS = ("jnp", "pallas", "auto")
 
@@ -234,19 +234,10 @@ def _sketch_t_block_jnp(B, seed, cols, row0, col0, kind, salt, scale,
 def _om_block(meta_ref, r_off, c_off, rows: int, cols: int, kind: str,
               salt: int, scale):
     """An Omega tile inside the kernel at meta's base + static tile offset."""
-    key0 = meta_ref[0]
-    key1 = meta_ref[1]
+    keys = (meta_ref[0], meta_ref[1])
     row0 = meta_ref[2] + jnp.uint32(r_off)
     col0 = meta_ref[3] + jnp.uint32(c_off)
-    if kind == "normal":
-        om = rng.philox_normal_grid(key0, key1, row0, col0, rows, cols, salt)
-    elif kind == "uniform":
-        om = rng.philox_uniform_grid(key0, key1, row0, col0, rows, cols, salt)
-    elif kind == "rademacher":
-        u = rng.philox_uniform_grid(key0, key1, row0, col0, rows, cols, salt)
-        om = jnp.where(u < 0.5, jnp.float32(-1), jnp.float32(1))
-    else:
-        raise ValueError(f"unknown omega kind {kind!r}")
+    om = rng.omega_grid(keys, row0, col0, rows, cols, kind, salt)
     if scale is not None:
         om = om * jnp.float32(scale)
     return om
@@ -326,7 +317,7 @@ def _t_body(meta_ref, b_ref, *refs, bk, bm, sk, nsteps_k, kind, salt, scale,
 
 def _meta(seed, row0, col0):
     """(4,) uint32 scalar-prefetch vector: key pair + global base offsets."""
-    k0, k1 = seed_keys(seed)
+    k0, k1 = rng.seed_keys(seed)
     return jnp.stack([k0, k1,
                       jnp.asarray(row0, jnp.uint32),
                       jnp.asarray(col0, jnp.uint32)])

@@ -1,8 +1,8 @@
 """Pure-jnp oracles for the Pallas kernels.
 
-The Omega construction calls the *same* Philox helpers as the kernel bodies,
-keyed by global coordinates, so oracle and kernel agree bitwise on Omega;
-results agree to float accumulation order.
+The Omega construction calls the *same* generator as the kernel bodies
+(``core.rng.omega_grid``), keyed by global coordinates, so oracle and
+kernel agree bitwise on Omega; results agree to float accumulation order.
 """
 from __future__ import annotations
 
@@ -13,19 +13,9 @@ from repro.core import rng
 
 def omega_ref(seed: int, n2: int, r: int, kind: str = "normal",
               salt: int = 0, dtype=jnp.float32):
-    key0 = jnp.uint32(seed & 0xFFFFFFFF)
-    key1 = jnp.uint32((seed >> 32) & 0xFFFFFFFF)
-    z = jnp.uint32(0)
-    if kind == "normal":
-        om = rng.philox_normal_grid(key0, key1, z, z, n2, r, salt)
-    elif kind == "uniform":
-        om = rng.philox_uniform_grid(key0, key1, z, z, n2, r, salt)
-    elif kind == "rademacher":
-        u = rng.philox_uniform_grid(key0, key1, z, z, n2, r, salt)
-        om = jnp.where(u < 0.5, jnp.float32(-1), jnp.float32(1))
-    else:
-        raise ValueError(kind)
-    return om.astype(dtype)
+    """The whole (n2, r) Omega, generated as one tile at offset (0, 0)."""
+    return rng.omega_grid(rng.seed_keys(seed), 0, 0, n2, r, kind,
+                          salt).astype(dtype)
 
 
 def sketch_matmul_ref(A, seed: int, r: int, kind: str = "normal",

@@ -44,26 +44,6 @@ from repro.core.sketch import F32
 
 
 # ---------------------------------------------------------------------------
-# In-kernel Omega tile (shared with the jnp reference — bitwise identical)
-# ---------------------------------------------------------------------------
-
-def _omega_tile_kernel(seed: int, row0, col0, rows: int, cols: int,
-                       kind: str, salt: int = 0):
-    key0 = jnp.uint32(seed & 0xFFFFFFFF)
-    key1 = jnp.uint32((seed >> 32) & 0xFFFFFFFF)
-    row0 = jnp.asarray(row0, jnp.uint32)
-    col0 = jnp.asarray(col0, jnp.uint32)
-    if kind == "normal":
-        return rng.philox_normal_grid(key0, key1, row0, col0, rows, cols, salt)
-    if kind == "uniform":
-        return rng.philox_uniform_grid(key0, key1, row0, col0, rows, cols, salt)
-    if kind == "rademacher":
-        u = rng.philox_uniform_grid(key0, key1, row0, col0, rows, cols, salt)
-        return jnp.where(u < 0.5, jnp.float32(-1), jnp.float32(1))
-    raise ValueError(f"unknown omega kind {kind!r}")
-
-
-# ---------------------------------------------------------------------------
 # B = A @ Omega
 # ---------------------------------------------------------------------------
 
@@ -101,7 +81,8 @@ def _sketch_matmul_body(a_ref, o_ref, acc_ref, *panel_ref, seed: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def tile():
-        return _omega_tile_kernel(seed, k * bk, j * bn, bk, bn, kind, salt)
+        return rng.omega_grid(rng.seed_keys(seed), k * bk, j * bn, bk, bn,
+                              kind, salt)
 
     if panel_ref:
         (panel_ref,) = panel_ref
@@ -198,7 +179,8 @@ def _sketch_t_matmul_body(b_ref, o_ref, acc_ref, *, seed: int, bk: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Omega tile rows k*bk..k*bk+bk map to the contraction; cols i*bm..
-    om = _omega_tile_kernel(seed, k * bk, i * bm, bk, bm, kind, salt)
+    om = rng.omega_grid(rng.seed_keys(seed), k * bk, i * bm, bk, bm, kind,
+                        salt)
     b = b_ref[...].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot(om.T, b, precision=F32,
                                 preferred_element_type=jnp.float32)
@@ -241,8 +223,8 @@ def _gen_omega_body(o_ref, *, seed: int, br: int, bc: int, kind: str,
                     salt: int):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    o_ref[...] = _omega_tile_kernel(seed, i * br, j * bc, br, bc, kind,
-                                    salt).astype(o_ref.dtype)
+    o_ref[...] = rng.omega_grid(rng.seed_keys(seed), i * br, j * bc, br, bc,
+                                kind, salt).astype(o_ref.dtype)
 
 
 def gen_omega_pallas(seed: int, n2: int, r: int, *,

@@ -3,7 +3,7 @@
 A cold chip run compiles every program it touches, and a Pallas kernel
 with its Philox generator takes seconds to minutes to compile.  The
 program entry points (``chip_smoke.py``, ``launch/serve.py``,
-``launch/train.py``, ``benchmarks/run.py``) call :func:`enable` once at
+``launch/train.py``, ``bench/run.py``) call :func:`enable` once at
 start-up; importing the library never does.
 
   * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and the cache

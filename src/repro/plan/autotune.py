@@ -481,7 +481,7 @@ def _plan_from_entry(plan: Plan, entry: dict) -> Optional[Plan]:
 # analytic defaults (MXU-aligned DEFAULT_BLOCKS, fused backend) pending a
 # hardware sweep — tagged ``"source": "analytic"`` so a report can tell
 # them from measured decisions; any local measurement overwrites them in
-# the writable cache.  See scripts in benchmarks/ for regenerating.
+# the writable cache.
 # ---------------------------------------------------------------------------
 
 def _preset(variant, grid=None, q_grid=None, blocks=None, backend="pallas",

@@ -5,7 +5,7 @@ Each client stream owns only its (Y, W) accumulator plus a Philox key pair.
 All streams with the same shape signature — (n1, n2, r, l, kind, corange,
 dtype, update-chunk shape) — share ONE compiled update executable: the
 per-stream seed enters the computation *traced* (as a uint32 key pair, see
-``core.sketch.seed_keys``), and for local row-block ingest the row offset is
+``core.rng.seed_keys``), and for local row-block ingest the row offset is
 traced too.  Opening stream number 1000 therefore costs a dict insert, not
 an XLA compile, which is what makes high stream fan-in viable.
 
@@ -60,8 +60,8 @@ from repro.core.sketch import (
     SPARSE_KINDS,
     input_sharding,
     rand_matmul,
-    seed_keys,
 )
+from repro.core.rng import seed_keys
 from repro.obs import ledger as obs_ledger
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -797,7 +797,7 @@ class SketchService:
     def sync(self):
         """Block until every in-flight device update has landed — resident
         lane buffers and stacked ragged cohorts alike.  The serving loop's
-        barrier (benchmarks; graceful drain) without per-lane slicing."""
+        barrier (graceful drain) without per-lane slicing."""
         leaves = [e for Yb, Wb in self._stacks.values()
                   for e in (Yb, Wb) if e is not None]
         for st in self._streams.values():

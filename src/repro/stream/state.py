@@ -51,7 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.sketch import (F32, SPARSE_KINDS, omega_tile, seed_keys,
+from repro.core.rng import seed_keys
+from repro.core.sketch import (F32, SPARSE_KINDS, omega_tile,
                                sparse_omega_rows, validate_kind)
 
 OMEGA_SALT = 0   # salt stream for Omega (range sketch)

@@ -754,7 +754,8 @@ def test_chaos_shrink_restore_not_run_on_one_device():
     assert out["shrink-restore"]["skipped"]
 
 
-@pytest.mark.parametrize("scenario", ["torn-write", "eviction-storm"])
+@pytest.mark.parametrize("scenario",
+                         ["kill-worker", "torn-write", "eviction-storm"])
 def test_chaos_scenarios_recover(scenario, tmp_path):
     out = faults.run_chaos_scenario(scenario, n1=64, n2=32, r=4, streams=3,
                                     updates=2, workdir=str(tmp_path),

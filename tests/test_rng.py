@@ -1,12 +1,16 @@
 """Philox-4x32 correctness + the tile-decomposition-invariance property that
 makes regeneration communication-free."""
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import rng
+from repro.core.sketch import omega_tile
+from repro.kernels import gen_omega
+from repro.kernels.ref import omega_ref
 
 
 # ---------------------------------------------------------------------------
@@ -112,22 +116,47 @@ def test_normal_moments_and_independence_across_salt():
     assert not np.array_equal(g1, g2)
 
 
-def test_block_omega_matches_omega_full():
-    key = jax.random.key(42)
-    n2, r, p2, p3 = 24, 8, 3, 2
-    full = rng.omega_full(key, n2, r, p2, p3)
-    br, bc = n2 // p2, r // p3
-    for j in range(p2):
-        for k in range(p3):
-            blk = rng.block_omega(key, j, k, br, bc, p3)
-            np.testing.assert_array_equal(
-                np.asarray(full[j * br:(j + 1) * br, k * bc:(k + 1) * bc]),
-                np.asarray(blk))
+# ---------------------------------------------------------------------------
+# One dense Omega generator: every front end returns omega_grid's bits
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind", ["normal", "uniform", "rademacher"])
+def test_omega_grid_is_every_front_end(kind):
+    """``rng.omega_grid`` at a non-zero offset is bitwise the tile that
+    ``core.sketch.omega_tile``, a slice of ``kernels.ref.omega_ref`` and
+    a slice of the interpreted ``gen_omega`` kernel give; an int seed, the
+    same seed as a traced uint32 scalar and as a (2,) key pair give the
+    same bits, and seed + 1 does not."""
+    seed, salt = 123, 2
+    r0, c0, rows, cols = 24, 8, 24, 16
+    n2, r = r0 + rows, c0 + cols
+    got = np.asarray(rng.omega_grid(rng.seed_keys(seed), r0, c0, rows, cols,
+                                    kind, salt))
+    assert got.dtype == np.float32
+
+    tile = omega_tile(seed, r0, c0, rows, cols, kind, salt=salt)
+    ref = omega_ref(seed, n2, r, kind, salt)[r0:, c0:]
+    kern = gen_omega(seed=seed, n2=n2, r=r, br=8, bc=8, kind=kind,
+                     salt=salt, interpret=True)[r0:, c0:]
+    for other in (tile, ref, kern):
+        np.testing.assert_array_equal(np.asarray(other), got)
+
+    def traced(s):
+        return rng.omega_grid(rng.seed_keys(s), r0, c0, rows, cols, kind,
+                              salt)
+
+    scalar = jax.jit(traced)(jnp.uint32(seed))
+    pair = jax.jit(traced)(jnp.array([seed, 0], jnp.uint32))
+    np.testing.assert_array_equal(np.asarray(scalar), got)
+    np.testing.assert_array_equal(np.asarray(pair), got)
+
+    big = 2 ** 33 + seed
+    np.testing.assert_array_equal(
+        np.asarray(traced(big)),
+        np.asarray(jax.jit(traced)(jnp.array([seed, 2], jnp.uint32))))
+    assert not np.array_equal(np.asarray(traced(seed + 1)), got)
 
 
-def test_philox_omega_full_deterministic():
-    a = rng.philox_omega_full(123, 32, 8)
-    b = rng.philox_omega_full(123, 32, 8)
-    c = rng.philox_omega_full(124, 32, 8)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert not np.array_equal(np.asarray(a), np.asarray(c))
+def test_omega_grid_rejects_an_unknown_kind():
+    with pytest.raises(ValueError, match="unknown omega kind"):
+        rng.omega_grid(rng.seed_keys(0), 0, 0, 8, 8, "countsketch")

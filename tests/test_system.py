@@ -3,8 +3,10 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 import jax
+import jax.numpy as jnp
 
 from dist_helper import SRC
 
@@ -22,6 +24,37 @@ def test_end_to_end_sketch_to_nystrom_single_device():
     Bn, C = nystrom_reference(S, 3, r)
     np.testing.assert_allclose(np.asarray(B), np.asarray(Bn), rtol=1e-5)
     assert float(relative_error(S, Bn, C)) < 1e-4
+
+
+def _tab2_kernel(name, n=128, d=24):
+    """Paper Tab. 2's kernels of n Gaussian points in d dimensions: linear
+    (rank d), RBF at sigma = ||X||/sqrt(n), and RBF at sigma = 1."""
+    X = jax.random.normal(jax.random.key(0), (n, d))
+    if name == "linear":
+        return X @ X.T
+    sq = jnp.sum(X * X, 1)
+    d2 = sq[:, None] + sq[None, :] - 2 * X @ X.T
+    sigma = (float(jnp.linalg.norm(X)) / n ** 0.5 if name == "rbf_scaled"
+             else 1.0)
+    return jnp.exp(-d2 / (2 * sigma ** 2))
+
+
+@pytest.mark.parametrize("name", ["linear", "rbf_scaled", "rbf_sigma1"])
+def test_nystrom_error_follows_tab2(name):
+    """Tab. 2's qualitative pattern at n = 128, d = 24: the linear kernel
+    is recovered to about f32 precision once r > d, the well-scaled RBF's
+    error falls with r, and the sigma = 1 RBF (nearly diagonal) stays
+    O(1)."""
+    from repro.core import nystrom_reference, relative_error
+    A = _tab2_kernel(name)
+    err = [float(relative_error(A, *nystrom_reference(A, 11, r)))
+           for r in (8, 16, 32)]
+    if name == "linear":
+        assert err[1] > 0.1 and err[2] < 1e-4, err
+    elif name == "rbf_scaled":
+        assert err[0] > err[1] > err[2] and err[2] < 0.2, err
+    else:
+        assert min(err) > 0.5, err
 
 
 def test_end_to_end_training_run():

@@ -350,7 +350,7 @@ def test_hlo_redistribute_byte_audit():
 import numpy as np, jax, jax.numpy as jnp
 from repro.core.grid import two_grid_shared_mesh
 from repro.core.nystrom import (_nystrom_two_grid_fused_prog, _spec_entry)
-from repro.core.sketch import seed_keys
+from repro.core.rng import seed_keys
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.roofline.hlo import collective_bytes_of
 assert len(jax.devices()) == 8
@@ -425,7 +425,7 @@ from repro.core.sketch import input_sharding
 from repro.core.compat import shard_map
 from repro.core.nystrom import _axes_index, _spec_entry
 from repro.kernels.local import sketch_block
-from repro.core.sketch import seed_keys
+from repro.core.rng import seed_keys
 assert len(jax.devices()) == 8
 
 seed, n, r = 11, 64, 16
